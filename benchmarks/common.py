@@ -287,7 +287,7 @@ def bench_projected_step(*, layers: int = 2, dim: int = 4096, rank: int = 256,
     shape = (layers, dim, dim)
     base = ProjectedAdamRule(rank=rank, projector="dct", residual="ef",
                              ef_dtype="q8", fused="off")
-    fused_mode = "on" if kops.ON_TPU else "fft"
+    fused_mode = "on" if kops.on_tpu() else "fft"
     result = {
         "bench": "optimizer_step",
         "api": "chain",
@@ -337,7 +337,7 @@ def bench_momentum_step(*, layers: int = 2, dim: int = 4096, rank: int = 256,
     from repro.optim.trion import TrionRule
 
     shape = (layers, dim, dim)
-    fused_mode = "on" if kops.ON_TPU else "fft"
+    fused_mode = "on" if kops.on_tpu() else "fft"
     out = {"leaf_shape": list(shape), "rank": rank,
            "fused_mode": fused_mode, "families": {}}
     cases = (

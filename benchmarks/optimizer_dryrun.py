@@ -41,7 +41,6 @@ def adaptive_rank_dryrun(arch: str, rank: int, *, rounds: int = 6,
     from repro.launch.mesh import make_production_mesh
     from repro.models import transformer as T
     from repro.optim.api import get_optimizer
-    from repro.parallel import compat
     from repro.parallel import sharding as sh
     from repro.telemetry.controllers import (RankAllocator,
                                              RankAllocatorConfig,
@@ -101,7 +100,7 @@ def adaptive_rank_dryrun(arch: str, rank: int, *, rounds: int = 6,
 
     # and the sharding layer must derive specs for the non-uniform state
     mesh = make_production_mesh()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         opt = get_optimizer("dct_adamw", lr=0.01, rank=rank,
                             overrides=allocator.overrides())
         p_specs = sh.params_specs(params_sds, mesh)
@@ -131,7 +130,6 @@ def main(argv=None):
     from repro.launch.mesh import make_production_mesh
     from repro.models import transformer as T
     from repro.optim.api import get_optimizer
-    from repro.parallel import compat
     from repro.parallel import sharding as sh
     from repro.roofline.analysis import analyze_compiled
 
@@ -141,7 +139,7 @@ def main(argv=None):
     for name in args.optimizers.split(","):
         kw = {} if name == "adamw" else {"rank": args.rank}
         opt = get_optimizer(name, lr=0.01, **kw)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             params_sds = jax.eval_shape(
                 partial(T.init_params, cfg, jax.random.PRNGKey(0)))
             p_specs = sh.params_specs(params_sds, mesh)

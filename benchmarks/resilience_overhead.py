@@ -76,7 +76,7 @@ def run(*, layers: int = 2, dim: int = 4096, rank: int = 256,
     from repro.kernels import ops as kops
     from repro.optim.projected_adam import ProjectedAdamRule
 
-    fused_mode = "on" if kops.ON_TPU else "fft"
+    fused_mode = "on" if kops.on_tpu() else "fft"
     shape = (layers, dim, dim)
     rule = ProjectedAdamRule(rank=rank, projector="dct", residual="ef",
                              ef_dtype="q8", fused=fused_mode)
@@ -97,8 +97,7 @@ def run(*, layers: int = 2, dim: int = 4096, rank: int = 256,
             rule, shape, guard=guard)
         # the guard must not knock the step off the fused execution layer
         spy.check(fused_mode)
-        ca = compiled.cost_analysis()
-        ca = ca[0] if isinstance(ca, list) else (ca or {})
+        ca = compiled.cost_analysis() or {}
         variants[label] = {"compiled": compiled, "grads": grads,
                            "params": params, "state": init(),
                            "peak": peak, "dispatch": dict(spy.counts),

@@ -58,6 +58,9 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     steps = 15 if args.fast else 40
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
+
     if args.tune_cache:
         from repro.tune import tuning_cache
         tuning_cache().load(args.tune_cache)

@@ -42,12 +42,11 @@ def _per_device_bytes(tree, dev) -> int:
 def measure_state_bytes(mesh, zcfg, *, layers=2, dim=4096, rank=256) -> dict:
     from repro.optim.api import get_optimizer
     from repro.parallel import sharding as sh
-    from repro.parallel.compat import set_mesh
 
     n_dp = mesh.size
     params = {"w": jnp.zeros((layers, dim, dim), jnp.float32)}
     opt = get_optimizer("dct_adamw", lr=0.01, rank=rank, zero=zcfg)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = opt.init(params)
         p_specs = sh.params_specs(params, mesh)
         o_specs = sh.opt_state_specs(state, params, p_specs, zero=zcfg,
@@ -89,13 +88,12 @@ def measure_step_time(mesh, zcfg, *, layers=2, dim=1024, rank=64,
                       steps=3, warmup=1) -> dict:
     from repro.optim.api import get_optimizer
     from repro.parallel import sharding as sh
-    from repro.parallel.compat import set_mesh
 
     params = {"w": jnp.zeros((layers, dim, dim), jnp.float32)}
     grads = {"w": jax.random.normal(jax.random.PRNGKey(0),
                                     (layers, dim, dim), jnp.float32)}
     rows = {}
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for label, zero in (("replicated", None), ("zero1", zcfg)):
             opt = get_optimizer("dct_adamw", lr=0.01, rank=rank, fused="fft",
                                 zero=zero)

@@ -31,11 +31,16 @@ _MODULES = {
 ARCHS = {name: mod.CONFIG for name, mod in _MODULES.items()}
 SMOKES = {name: mod.SMOKE for name, mod in _MODULES.items()}
 
-# the paper's own models, addressable the same way
-ARCHS["llama-30m"] = llama_paper.LLAMA_30M
-ARCHS["llama-350m"] = llama_paper.LLAMA_350M
-ARCHS["llama-800m"] = llama_paper.LLAMA_800M
-ARCHS["llama-1.3b"] = llama_paper.LLAMA_1_3B
+# the paper's own models, addressable the same way, each with a reduced
+# same-family smoke config for CPU rehearsals
+_PAPER = {
+    "llama-30m": llama_paper.LLAMA_30M,
+    "llama-350m": llama_paper.LLAMA_350M,
+    "llama-800m": llama_paper.LLAMA_800M,
+    "llama-1.3b": llama_paper.LLAMA_1_3B,
+}
+ARCHS.update(_PAPER)
+SMOKES.update({name: cfg.reduced() for name, cfg in _PAPER.items()})
 
 ASSIGNED = tuple(_MODULES)          # the 10 graded architectures
 

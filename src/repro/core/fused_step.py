@@ -80,7 +80,7 @@ def resolve(mode: str) -> str:
     if mode == "auto":
         mode = _DEFAULT_MODE
     if mode == "auto":
-        return "on" if ops.ON_TPU else "off"
+        return "on" if ops.on_tpu() else "off"
     return mode
 
 

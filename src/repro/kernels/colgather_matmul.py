@@ -33,8 +33,10 @@ hardcoded ``DEFAULT_BLOCK`` on a miss (the bit-identical untuned path).
 per-column scale of the gathered matrix would depend on ``idx``; instead
 ``Q^T`` is int8-quantized per-row pre-gather and those row scales are
 folded into ``b`` before ``b``'s own per-row quantization (kernels/lowp.py
-derivation), leaving one per-row epilogue scale — and an int8 gather
-scratch, 4x smaller in VMEM.
+derivation), leaving one per-row epilogue scale. The int8 ``Q^T`` stripe
+travels packed four rows to an int32 word (``_pack_rows_i8``), so it keeps
+the int8 footprint while the gather moves 32-bit rows, which Mosaic can
+address at any row offset.
 """
 from __future__ import annotations
 
@@ -57,6 +59,31 @@ def _build_gather(idx_ref, bi, qt_ref, gather_ref, r: int):
     def body(k, _):
         row = idx_ref[bi, k]
         gather_ref[pl.ds(k, 1), :] = qt_ref[pl.ds(row, 1), :]
+        return ()
+
+    jax.lax.fori_loop(0, r, body, ())
+
+
+def _pack_rows_i8(x: jax.Array) -> jax.Array:
+    """(n, c) int8 -> (ceil(n/4), c) int32, byte ``s`` of word ``i`` holding
+    row ``4i+s``. Mosaic cannot load one int8 row at a dynamic offset (the
+    int8 tile is 32 rows), but it can load one 32-bit row; the packed
+    stripe keeps the int8 footprint in HBM and VMEM."""
+    pad = -x.shape[0] % 4
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+    b = (x.astype(jnp.int32) & 0xFF).reshape(-1, 4, x.shape[1])
+    return (b[:, 0] | (b[:, 1] << 8) | (b[:, 2] << 16) | (b[:, 3] << 24))
+
+
+def _build_gather_packed(idx_ref, bi, qt_ref, gather_ref, r: int):
+    """Row gather out of a ``_pack_rows_i8`` stripe into an int32 scratch:
+    shift byte ``row % 4`` of word row ``row // 4`` to the top, then an
+    arithmetic shift back sign-extends it."""
+    def body(k, _):
+        row = idx_ref[bi, k]
+        word = qt_ref[pl.ds(row // 4, 1), :]
+        gather_ref[pl.ds(k, 1), :] = (word << (24 - 8 * (row % 4))) >> 24
         return ()
 
     jax.lax.fori_loop(0, r, body, ())
@@ -97,15 +124,16 @@ def _kernel_dual(idx_ref, b1_ref, b2_ref, qt_ref, o1_ref, o2_ref, gather_ref,
 
 def _kernel_q8(idx_ref, b_ref, sb_ref, qt_ref, out_ref, gather_ref, *,
                r: int):
-    """int8: gathered rows stay int8, exact int32 dot, per-row epilogue."""
+    """int8: rows gathered from the packed stripe, exact int32 dot, per-row
+    epilogue."""
     bi = pl.program_id(0)
     i = pl.program_id(2)
 
     @pl.when(i == 0)
     def _gather():
-        _build_gather(idx_ref, bi, qt_ref, gather_ref, r)
+        _build_gather_packed(idx_ref, bi, qt_ref, gather_ref, r)
 
-    acc = jnp.dot(b_ref[0], gather_ref[...],
+    acc = jnp.dot(b_ref[0], gather_ref[...].astype(jnp.int8),
                   preferred_element_type=jnp.int32)
     out_ref[0] = (acc.astype(jnp.float32) * sb_ref[0]).astype(out_ref.dtype)
 
@@ -117,9 +145,9 @@ def _kernel_dual_q8(idx_ref, b1_ref, s1_ref, b2_ref, s2_ref, qt_ref,
 
     @pl.when(i == 0)
     def _gather():
-        _build_gather(idx_ref, bi, qt_ref, gather_ref, r)
+        _build_gather_packed(idx_ref, bi, qt_ref, gather_ref, r)
 
-    qr = gather_ref[...]
+    qr = gather_ref[...].astype(jnp.int8)
     a1 = jnp.dot(b1_ref[0], qr, preferred_element_type=jnp.int32)
     o1_ref[0] = (a1.astype(jnp.float32) * s1_ref[0]).astype(o1_ref.dtype)
     a2 = jnp.dot(b2_ref[0], qr, preferred_element_type=jnp.int32)
@@ -169,15 +197,16 @@ def _call(bs, qt, idx, *, block, interpret, out_dtype, compute_dtype):
                 pl.BlockSpec((1, bm, r), lambda b, j, i, idx_ref: (b, i, 0)),
                 pl.BlockSpec((1, bm, 1), lambda b, j, i, idx_ref: (b, i, 0)),
             ]
-        qtp = jnp.pad(qt_q, ((0, 0), (0, np_))) if np_ else qt_q
+        qtp = _pack_rows_i8(
+            jnp.pad(qt_q, ((0, 0), (0, np_))) if np_ else qt_q)
         in_specs.append(
-            pl.BlockSpec((n, bn), lambda b, j, i, idx_ref: (0, j)))
+            pl.BlockSpec((qtp.shape[0], bn), lambda b, j, i, idx_ref: (0, j)))
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(nb, nj, ni),
             in_specs=in_specs,
             out_specs=out_specs,
-            scratch_shapes=[pltpu.VMEM((r, bn), jnp.int8)],
+            scratch_shapes=[pltpu.VMEM((r, bn), jnp.int32)],
         )
         kernel = _kernel_q8 if nops == 1 else _kernel_dual_q8
         outs = pl.pallas_call(

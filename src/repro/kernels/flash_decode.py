@@ -10,11 +10,12 @@ is never densified in HBM.
 
 Structure (mirrors ``flash_attention.py``):
 
-  * GQA head-grouping — q is laid out ``(B*Hkv, group, hd)`` so every
-    grid row loads one K/V block once and attends all ``group`` query
-    heads of that kv head against it (the same ``q_head // group``
-    folding as the prefill kernel, moved into the row layout because
-    decode's q is a single token).
+  * GQA head-grouping — q is laid out ``(B, Hkv, group, hd)``. Every
+    grid row is one slot: it DMAs a whole ``(bs, Hkv, hd)`` pool block
+    once and, in a static loop over the kv heads, attends that head's
+    ``group`` query heads against it (the same ``q_head // group``
+    folding as the prefill kernel). A block of the whole head axis is
+    what the TPU lowering accepts: its last two dims equal the pool's.
   * Split-KV parallelism — the block-table walk is split into
     ``num_splits`` *parallel* grid rows, each producing an unnormalized
     partial ``(acc, m, l)`` online-softmax state over its share of the
@@ -47,9 +48,8 @@ def _kernel(table_ref, lengths_ref, q_ref, k_ref, v_ref,
             o_ref, m_ref, l_ref, acc_ref, ms_ref, ls_ref, *,
             hkv: int, bps: int, bs: int, group: int,
             window: int | None, scale: float):
-    bh = pl.program_id(1)
+    b = pl.program_id(1)
     j = pl.program_id(2)
-    b = bh // hkv
 
     @pl.when(j == 0)
     def _init():
@@ -66,26 +66,27 @@ def _kernel(table_ref, lengths_ref, q_ref, k_ref, v_ref,
 
     @pl.when(run)
     def _block():
-        q = q_ref[0].astype(jnp.float32)                 # (group, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)           # (bs, hd)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
         k_pos = start + jax.lax.broadcasted_iota(jnp.int32, (group, bs), 1)
         mask = k_pos < length
         if window is not None:
             mask = jnp.logical_and(mask, k_pos >= length - window)
-        s = jnp.where(mask, s, NEG_INF)
+        for h in range(hkv):                             # static head loop
+            q = q_ref[0, h].astype(jnp.float32)          # (group, hd)
+            k = k_ref[0, :, h, :].astype(jnp.float32)    # (bs, hd)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(mask, s * scale, NEG_INF)
 
-        m_prev = ms_ref[...]                             # (group, 1)
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        ls_ref[...] = ls_ref[...] * corr + p.sum(axis=1, keepdims=True)
-        v = v_ref[0, :, 0].astype(jnp.float32)           # (bs, hd)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ms_ref[...] = m_new
+            m_prev = ms_ref[h]                           # (group, 1)
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            ls_ref[h] = ls_ref[h] * corr + p.sum(axis=1, keepdims=True)
+            v = v_ref[0, :, h, :].astype(jnp.float32)    # (bs, hd)
+            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            ms_ref[h] = m_new
 
     @pl.when(j == bps - 1)
     def _finalize():
@@ -125,48 +126,51 @@ def flash_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     lengths = lengths.astype(jnp.int32)
     scale = 1.0 / math.sqrt(hd)
 
-    # (B, Hkv, group, hd) -> (B*Hkv, group, hd): row r serves kv head
-    # r % Hkv of batch r // Hkv
-    qf = q.reshape(b, hkv, group, hd).reshape(b * hkv, group, hd)
+    qf = q.reshape(b, hkv, group, hd)
 
-    def kv_index(s, bh, j, table_ref, lengths_ref):
-        return (table_ref[bh // hkv, s * bps + j], 0, bh % hkv, 0)
+    def kv_index(s, bi, j, table_ref, lengths_ref):
+        return (table_ref[bi, s * bps + j], 0, 0, 0)
+
+    def row_index(s, bi, j, table_ref, lengths_ref):
+        return (s, bi, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(num_splits, b * hkv, bps),
+        grid=(num_splits, b, bps),
         in_specs=[
-            pl.BlockSpec((1, group, hd), lambda s, bh, j, t, ln: (bh, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hd), kv_index),
-            pl.BlockSpec((1, bs, 1, hd), kv_index),
+            pl.BlockSpec((1, hkv, group, hd),
+                         lambda s, bi, j, t, ln: (bi, 0, 0, 0)),
+            pl.BlockSpec((1, bs, hkv, hd), kv_index),
+            pl.BlockSpec((1, bs, hkv, hd), kv_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, group, hd), lambda s, bh, j, t, ln: (s, bh, 0, 0)),
-            pl.BlockSpec((1, 1, group, 1), lambda s, bh, j, t, ln: (s, bh, 0, 0)),
-            pl.BlockSpec((1, 1, group, 1), lambda s, bh, j, t, ln: (s, bh, 0, 0)),
+            pl.BlockSpec((1, 1, hkv, group, hd), row_index),
+            pl.BlockSpec((1, 1, hkv, group, 1), row_index),
+            pl.BlockSpec((1, 1, hkv, group, 1), row_index),
         ],
         scratch_shapes=[
-            pltpu.VMEM((group, hd), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
+            pltpu.VMEM((hkv, group, hd), jnp.float32),
+            pltpu.VMEM((hkv, group, 1), jnp.float32),
+            pltpu.VMEM((hkv, group, 1), jnp.float32),
         ],
     )
+    part = (num_splits, b, hkv, group)
     o_part, m_part, l_part = pl.pallas_call(
         functools.partial(_kernel, hkv=hkv, bps=bps, bs=bs, group=group,
                           window=window, scale=scale),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((num_splits, b * hkv, group, hd), jnp.float32),
-            jax.ShapeDtypeStruct((num_splits, b * hkv, group, 1), jnp.float32),
-            jax.ShapeDtypeStruct((num_splits, b * hkv, group, 1), jnp.float32),
+            jax.ShapeDtypeStruct((*part, hd), jnp.float32),
+            jax.ShapeDtypeStruct((*part, 1), jnp.float32),
+            jax.ShapeDtypeStruct((*part, 1), jnp.float32),
         ],
         interpret=interpret,
     )(table, lengths, qf, k_pool, v_pool)
 
     # online-softmax merge across splits (all-empty slots stay zero)
-    m_star = jnp.max(m_part, axis=0, keepdims=True)      # (1, BH, g, 1)
+    m_star = jnp.max(m_part, axis=0, keepdims=True)      # (1, B, Hkv, g, 1)
     alpha = jnp.exp(m_part - jnp.maximum(m_star, NEG_INF / 2))
-    l_tot = jnp.sum(alpha * l_part, axis=0)              # (BH, g, 1)
-    acc = jnp.sum(alpha * o_part, axis=0)                # (BH, g, hd)
+    l_tot = jnp.sum(alpha * l_part, axis=0)              # (B, Hkv, g, 1)
+    acc = jnp.sum(alpha * o_part, axis=0)                # (B, Hkv, g, hd)
     out = acc / jnp.maximum(l_tot, 1e-30)
-    return out.reshape(b, hkv, group, hd).reshape(b, hq, hd).astype(q.dtype)
+    return out.reshape(b, hq, hd).astype(q.dtype)

@@ -11,7 +11,9 @@ Two fused passes used by DCT-AdamW's quantized EF (paper §2.4):
 Leading stacked-layer axes are collapsed into a leading batch grid dimension
 (scan-stacked ``(layers, m, n)`` leaves run in one launch). Rows are
 processed in full width per grid step so the per-row amax reduction and the
-scaling stay in registers/VMEM.
+scaling stay in registers/VMEM. Full-width rows make a block's VMEM grow with
+``n``, so the row count per step is capped by ``_VMEM_BUDGET`` (a wide leaf
+such as 27648 x 5120 takes fewer rows per step, never partial rows).
 """
 from __future__ import annotations
 
@@ -27,6 +29,14 @@ from repro.tune.cache import resolve_block
 from .lowp import q8_scale
 
 DEFAULT_BM = 256  # rows per grid step
+
+# Scoped VMEM (16 MiB by default on TPU v5e) holds every block twice
+# (double-buffering): dequant_add_ef moves an fp32 G block, an int8 payload
+# block and an fp32 output block, 9 bytes per element, 18 double-buffered.
+# 12 MiB leaves room for the kernels' temporaries.
+_VMEM_BUDGET = 12 * 2**20
+_BYTES_PER_ELEM = 18
+_ROW_ALIGN = 32       # int8 sublane tile: the payload block's row multiple
 
 
 def _quant_kernel(x_ref, q_ref, scale_ref):
@@ -60,12 +70,15 @@ def _batch_rows(x, bm):
 
 def _resolve_bm(x: jax.Array, bm):
     """``bm=None`` -> TuningCache -> ``DEFAULT_BM`` (both EF kernels share
-    the one "quant_ef" cache family)."""
+    the one "quant_ef" cache family), capped so a block fits the VMEM
+    budget at this row width."""
     if bm is not None:
         return int(bm)
     *batch, m, n = x.shape
-    return int(resolve_block("quant_ef", (math.prod(batch), m, n), 0,
-                             x.dtype, DEFAULT_BM))
+    bm = int(resolve_block("quant_ef", (math.prod(batch), m, n), 0,
+                           x.dtype, DEFAULT_BM))
+    cap = _VMEM_BUDGET // (_BYTES_PER_ELEM * n) // _ROW_ALIGN * _ROW_ALIGN
+    return min(bm, max(cap, _ROW_ALIGN))
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret"))

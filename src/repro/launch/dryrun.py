@@ -32,7 +32,6 @@ from repro.configs.registry import ARCHS, ASSIGNED
 from repro.configs.shapes import SHAPES, batch_specs, skip_reason
 from repro.launch.mesh import make_production_mesh
 from repro.models import transformer as T
-from repro.parallel import compat
 from repro.optim.api import get_optimizer
 from repro.parallel import sharding as sh
 from repro.roofline.analysis import analyze_compiled, model_flops
@@ -176,7 +175,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     # layout scoped per cell (not a process-global): concurrent run_cell
     # calls under different layouts cannot race each other's specs
     with sh.use_policy(layout=eff_layout, seq_parallel=False), \
-            compat.set_mesh(mesh):
+            jax.set_mesh(mesh):
         if spec.kind == "train":
             lowered = _train_lowered(cfg, mesh, optimizer, rank, shape_name,
                                      accum_dtype)
@@ -198,7 +197,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     if verbose:
         print(f"== {arch} x {shape_name} x {mesh_name} ==")
         print("memory_analysis:", compiled.memory_analysis())
-        ca = compat.cost_analysis(compiled)
+        ca = compiled.cost_analysis()
         print("xla cost_analysis (loop bodies once): flops=%.3e bytes=%.3e"
               % (ca.get("flops", 0), ca.get("bytes accessed", 0)))
         print("trip-aware per-device: flops=%.3e bytes=%.3e"

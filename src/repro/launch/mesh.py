@@ -5,21 +5,22 @@ touches jax device state. Single-pod: (data=16, model=16) = 256 chips;
 multi-pod: (pod=2, data=16, model=16) = 512 chips. ``pod`` and ``data``
 jointly form the FSDP/batch axes; ``model`` is TP/EP.
 
-Use ``with compat.set_mesh(mesh):`` around lowering — that installs the
-mesh that repro.parallel.sharding reads (abstract mesh on current jax,
-thread-resources physical mesh on older releases).
+Use ``with jax.set_mesh(mesh):`` around lowering — that installs the
+mesh that repro.parallel.sharding reads.
 """
 from __future__ import annotations
 
-from repro.parallel import compat
+import jax
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Arbitrary mesh for experiments (e.g. scaling the pod axis)."""
-    return compat.make_mesh(shape, axes)
+    """Arbitrary mesh for experiments (e.g. scaling the pod axis), every
+    axis of type Auto."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -4,17 +4,24 @@
       --rank 256 --steps 300 --seq-len 512 --batch 64 \
       --ckpt-dir /tmp/ckpt [--supervise] [--smoke]
 
-On a real TPU deployment this binary runs once per host under the
-production mesh; here (CPU container) it runs single-process, exercising
-the identical code path: config -> data pipeline -> jit'd train_step with
-the paper's optimizer -> checkpoint manager -> supervisor restarts.
-``--supervise`` wraps the run in the restart supervisor (crash -> resume
-from the latest checkpoint with backoff).
+On a TPU host this binary runs once per host; with ``JAX_PLATFORMS=cpu``
+it runs the same path with the Pallas kernels in interpret mode: config ->
+data pipeline -> jit'd train_step with the paper's optimizer -> checkpoint
+manager -> supervisor restarts. ``--supervise`` wraps the run in the
+restart supervisor (crash -> resume from the latest checkpoint with
+backoff); the parent touches no JAX backend, so the child can take the
+chip.
+
+:func:`train` is the run itself and returns what it did (final state,
+per-step metrics, the jitted step); :func:`main` is the command line
+around it.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import sys
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
@@ -130,6 +137,19 @@ def build(argv=None):
     return ap.parse_args(argv)
 
 
+@dataclasses.dataclass
+class TrainRun:
+    """What :func:`train` did: the final state, one scalar-metrics dict per
+    committed step, the jitted step and the batch function it ran on, and
+    the concrete fused dispatch mode of the optimizer (None for presets
+    without one)."""
+    state: Any
+    history: list[dict]
+    step_fn: Callable
+    batch_fn: Callable
+    fused: str | None
+
+
 def main(argv=None) -> int:
     args = build(argv)
     if args.supervise:
@@ -142,7 +162,30 @@ def main(argv=None) -> int:
                        if args.ckpt_dir else None)
         return supervise(child, progress_fn=progress_fn)
 
+    from repro.launch.cache import enable_compile_cache
+    from repro.train.resilience import HALT_EXIT_CODE, TrainingHalted
+
+    enable_compile_cache()
+    try:
+        run = train(args)
+    except TrainingHalted as e:
+        # rung 4: deterministic divergence — the diagnostic dump is already
+        # on disk; the exit code tells the supervisor not to restart
+        print(f"[train] halted: {e}")
+        return HALT_EXIT_CODE
+    if run.history:
+        print(f"[train] done at step {int(run.state.step)}: "
+              f"loss {float(run.history[-1]['loss']):.4f}")
+    return 0
+
+
+def train(args: argparse.Namespace) -> TrainRun:
+    """Run the training job ``args`` (from :func:`build`) in this process.
+    Raises ``SystemExit`` on an invalid flag combination and
+    ``TrainingHalted`` when the resilience ladder halts."""
     from repro.configs.registry import get_config
+    from repro.core import fused_step
+    from repro.parallel import sharding as sh
     from repro.data.synthetic import make_batch_fn
     from repro.optim.api import get_optimizer
     from repro.train.loop import Trainer
@@ -199,7 +242,6 @@ def main(argv=None) -> int:
             # the lowp mirror only exists on the fused paths; fail at the
             # CLI instead of deep inside the first trace (fused="auto"
             # resolves to the reference path off-TPU)
-            from repro.core import fused_step
             if fused_step.resolve(args.fused or "auto") == "off":
                 raise SystemExit(
                     f"--compute-dtype {args.compute_dtype} requires a fused "
@@ -243,7 +285,16 @@ def main(argv=None) -> int:
         zero_cfg = ZeroConfig(mode=args.zero)
         opt_kw["zero"] = zero_cfg
         if jax.device_count() > 1:
+            # data parallelism over every visible device (the pure_dp
+            # layout): the batch splits over the "data" axis, parameters
+            # replicate, the optimizer state partitions. Without --zero
+            # the run keeps one device: outside ZeRO's shard_map the Pallas
+            # kernels would sit in a program partitioned over the mesh,
+            # which Mosaic refuses.
             from repro.launch.mesh import make_mesh
+            if args.batch % jax.device_count():
+                raise SystemExit(f"--batch {args.batch} does not split "
+                                 f"over {jax.device_count()} devices")
             mesh = make_mesh((jax.device_count(),), ("data",))
         else:
             print("[train] --zero requested with a single visible device; "
@@ -309,6 +360,16 @@ def main(argv=None) -> int:
 
     def trainer_batch_fn(s):
         return batch_fn(jnp.int32(s))
+    if mesh is not None:
+        # data parallelism: each device takes its rows of the batch; an
+        # unplaced batch would sit wholly on device 0
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        rows = NamedSharding(mesh, PartitionSpec("data"))
+        unplaced_batch_fn = trainer_batch_fn
+
+        def trainer_batch_fn(s):
+            return jax.device_put(unplaced_batch_fn(s), rows)
     if chaos_plan is not None:
         trainer_batch_fn = chaos_plan.wrap_batch_fn(trainer_batch_fn)
 
@@ -359,15 +420,15 @@ def main(argv=None) -> int:
             # over the data axis) and install it at init; the Trainer also
             # uses it to re-partition on checkpoint restore, so the DP
             # width may change across restarts (docs/distributed.md)
-            from repro.parallel import sharding as sh
             from repro.train.steps import TrainState
             from jax.sharding import PartitionSpec as P
 
             state_sds = jax.eval_shape(init_fn)
-            p_specs = sh.params_specs(state_sds.params, mesh)
-            o_specs = sh.opt_state_specs(state_sds.opt_state,
-                                         state_sds.params, p_specs,
-                                         zero=zero_cfg, mesh=mesh)
+            with sh.use_policy(layout="pure_dp"):
+                p_specs = sh.params_specs(state_sds.params, mesh)
+                o_specs = sh.opt_state_specs(state_sds.opt_state,
+                                             state_sds.params, p_specs,
+                                             zero=zero_cfg, mesh=mesh)
             shardings = sh.named_shardings(
                 TrainState(P(), p_specs, o_specs), mesh)
             trainer_kw["state_shardings"] = shardings
@@ -378,19 +439,12 @@ def main(argv=None) -> int:
             train_step=step_fn, init_state_fn=init_fn,
             batch_fn=trainer_batch_fn, **trainer_kw)
 
-    from repro.train.resilience import HALT_EXIT_CODE, TrainingHalted
     try:
         if mesh is not None:
-            from repro.parallel import compat
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh), sh.use_policy(layout="pure_dp"):
                 state = trainer.run(total_steps=args.steps)
         else:
             state = trainer.run(total_steps=args.steps)
-    except TrainingHalted as e:
-        # rung 4: deterministic divergence — the diagnostic dump is already
-        # on disk; the exit code tells the supervisor not to restart
-        print(f"[train] halted: {e}")
-        return HALT_EXIT_CODE
     finally:
         if sink is not None:
             sink.close()
@@ -402,13 +456,13 @@ def main(argv=None) -> int:
             trace = obs_mod.write_chrome_trace(
                 os.path.join(args.obs_dir, "trace.json"))
             print(f"[train] obs artifacts: {prom}, {trace}")
-    final = trainer.metrics_history[-1] if trainer.metrics_history else {}
-    if final:
-        print(f"[train] done at step {int(state.step)}: "
-              f"loss {float(final['loss']):.4f}")
     if adaptive and args.adaptive_rank:
         print(f"[train] final rank allocation: {allocator.alloc}")
-    return 0
+    fused = (fused_step.resolve(args.fused or "auto")
+             if args.optimizer in FUSED_FAMILY else None)
+    return TrainRun(state=state, history=trainer.metrics_history,
+                    step_fn=trainer.train_step, batch_fn=trainer_batch_fn,
+                    fused=fused)
 
 
 if __name__ == "__main__":

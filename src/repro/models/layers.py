@@ -14,7 +14,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.parallel.compat import shard_map
 from repro.parallel.sharding import shard
 
 
@@ -207,7 +206,7 @@ def sp_blockwise_attention(q, k, v, *, causal: bool, window=None,
                                    q_chunk=min(q_chunk, s_loc),
                                    kv_chunk=kv_chunk, q_offset=off)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dps, tp, None, None), P(dps, None, None, None),
                   P(dps, None, None, None)),

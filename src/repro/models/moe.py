@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel.compat import shard_map
 from repro.parallel.sharding import active_mesh, dp_axes, tp_axis
 
 from .layers import dense_init
@@ -157,7 +156,7 @@ def moe_ffn(params: dict, x: jax.Array, cfg):
                 out = jax.lax.psum(out, dp)    # combine f-partials
             return out, aux
 
-        out, aux = shard_map(
+        out, aux = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(x_spec, P(None, None), up_spec, up_spec, dn_spec),

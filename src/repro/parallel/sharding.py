@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.parallel import compat
 
 DP_AXES = ("pod", "data")   # batch/FSDP axes (present subset is used)
 TP_AXIS = "model"
@@ -101,7 +100,9 @@ def seq_parallel() -> bool:
 
 
 def active_mesh():
-    return compat.get_active_mesh()
+    """The mesh installed by ``jax.set_mesh``, or None."""
+    m = jax.sharding.get_abstract_mesh()
+    return m if m.axis_names else None
 
 
 def dp_axes(mesh=None) -> tuple[str, ...]:

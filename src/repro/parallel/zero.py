@@ -54,7 +54,7 @@ import jax
 from jax.sharding import PartitionSpec as P
 
 from repro.optim.common import deorient, orient_right
-from repro.parallel import compat
+from repro.parallel.sharding import active_mesh
 
 ZERO_MODES = ("off", "1")
 
@@ -112,7 +112,7 @@ def resolve(cfg: ZeroConfig | None) -> ZeroContext | None:
     (mode off, no mesh, configured axes absent, or a 1-wide shard set)."""
     if cfg is None or not cfg.active:
         return None
-    mesh = compat.get_active_mesh()
+    mesh = active_mesh()
     axes = present_axes(mesh, cfg)
     if not axes:
         return None
@@ -232,7 +232,7 @@ def sharded_leaf_update(rule, g, state, param, ctx, zctx: ZeroContext):
         d, new_s = rule.update(g_blk, s_blk, p_blk, inner)
         return d, new_s, (cap.stats if capture else None)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local, mesh=zctx.mesh,
         in_specs=(gspec, sspecs, P(), P(), P(), P()),
         out_specs=(gspec, sspecs, P()),

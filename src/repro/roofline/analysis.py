@@ -177,11 +177,10 @@ def analyze_compiled(compiled, *, arch: str, shape: str, mesh_name: str,
                      n_devices: int, model_flops_total: float,
                      tp_degree: int = 16, compile_s: float = 0.0,
                      device_arch: str | None = None) -> RooflineReport:
-    from repro.parallel import compat
 
     from .hlo_cost import module_costs
 
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     txt = compiled.as_text()
     # primary: our trip-count-aware, dtype-correct walker (XLA's analysis
     # counts scan bodies once and the CPU backend pads bf16 with fp32

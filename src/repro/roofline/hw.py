@@ -4,9 +4,11 @@ The seed shipped TPU v5e constants hardcoded at module level, which made
 every roofline prediction (and now the tune/ autotuner's block-grid
 pruning) silently wrong on any other target. The constants live in an
 arch table instead: ``get_arch("v5p")`` / ``set_arch("a100")`` /
-``REPRO_ARCH=a100`` select the spec, and the legacy module-level names
-(``PEAK_FLOPS_BF16`` etc.) remain as the **v5e defaults** for call sites
-that predate the table.
+``REPRO_ARCH=a100`` select the spec; with none of those, a process on a
+TPU takes the arch of its ``device_kind`` (an unknown TPU kind is an
+error, never a default) and any other backend plans against v5e. The
+legacy module-level names (``PEAK_FLOPS_BF16`` etc.) remain as the **v5e
+defaults** for call sites that predate the table.
 
 ``cpu-est`` is a deliberately rough order-of-magnitude stand-in for the
 CI container (AVX-class core, DDR bandwidth): good enough to classify a
@@ -59,6 +61,13 @@ ARCHS: dict[str, ArchSpec] = {
                         vmem_bytes=32 * 1024**2, int8_flops=200e9),
 }
 
+#: ``device_kind`` strings JAX reports for TPU chips -> arch table entry
+TPU_DEVICE_KINDS = {
+    "TPU v5 lite": "v5e",
+    "TPU v5": "v5p",
+    "TPU v5p": "v5p",
+}
+
 _DEFAULT_ARCH = "v5e"
 _ACTIVE: str | None = None
 
@@ -67,11 +76,30 @@ def arch_names() -> tuple[str, ...]:
     return tuple(ARCHS)
 
 
+def arch_for_device_kind(kind: str) -> str:
+    """Arch name of a TPU ``device_kind`` (e.g. "TPU v5 lite" -> "v5e");
+    raises on a kind the table does not know."""
+    try:
+        return TPU_DEVICE_KINDS[kind]
+    except KeyError:
+        raise ValueError(f"unknown TPU device_kind {kind!r}; known: "
+                         f"{sorted(TPU_DEVICE_KINDS)}") from None
+
+
+def _device_arch() -> str:
+    """The arch of this process's TPU; v5e on any other backend."""
+    import jax
+
+    if jax.default_backend() != "tpu":
+        return _DEFAULT_ARCH
+    return arch_for_device_kind(jax.devices()[0].device_kind)
+
+
 def get_arch(name: str | None = None) -> ArchSpec:
     """Resolve an arch spec: explicit ``name`` > ``set_arch`` >
-    ``REPRO_ARCH`` env > the v5e default (the seed behavior)."""
+    ``REPRO_ARCH`` env > the TPU's ``device_kind`` > v5e off-TPU."""
     if name is None:
-        name = _ACTIVE or os.environ.get("REPRO_ARCH", _DEFAULT_ARCH)
+        name = _ACTIVE or os.environ.get("REPRO_ARCH") or _device_arch()
     try:
         return ARCHS[name]
     except KeyError:

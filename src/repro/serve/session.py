@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-_NEG = jnp.float32(-1e30)
+_NEG = np.float32(-1e30)   # numpy: building it touches no device
 
 
 # ---------------------------------------------------------------------------

@@ -109,7 +109,7 @@ def measure(kernel: str, shape, rank: int, dtype, block, *,
     compile+run calls)."""
     if interpret is None:
         from repro.kernels import ops
-        interpret = not ops.ON_TPU
+        interpret = not ops.on_tpu()
     if operands is None:
         operands = _operands(kernel, shape, rank, dtype)
     run = _runner(kernel, operands, block, interpret)

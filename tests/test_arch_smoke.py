@@ -119,3 +119,18 @@ def test_full_configs_have_exact_dims():
             A["deepseek-moe-16b"].moe_d_ff) == (64, 6, 1408)
     assert (A["jamba-1.5-large-398b"].n_experts,
             A["jamba-1.5-large-398b"].moe_top_k) == (16, 2)
+
+
+def test_cli_paper_model_smoke():
+    """``--arch llama-350m --smoke`` (the CLI's default arch) trains in
+    process; on the CPU ``--fused auto`` resolves to the reference path."""
+    from repro.launch.train import build, train
+
+    run = train(build(["--arch", "llama-350m", "--smoke",
+                       "--optimizer", "dct_adamw", "--rank", "8",
+                       "--steps", "2", "--seq-len", "16", "--batch", "2",
+                       "--log-every", "1"]))
+    assert len(run.history) == 2
+    assert all(np.isfinite(float(m["loss"])) for m in run.history)
+    assert run.fused == "off"
+    assert int(run.state.step) == 2

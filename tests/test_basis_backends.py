@@ -4,9 +4,9 @@ Covers the backend property contract (orthonormality across awkward
 orders, fast-path == matmul-path parity incl. the Hadamard odd-n
 fallback), the registry-sourced unknown-kind errors, the process-wide
 BasisCache (adaptive-rebuild hit counter), the per-backend captured-energy
-telemetry invariant, the DCT bit-identity pin against the pre-refactor
-outputs, and a reduced ZeRO-1 parity check per backend (8 forced host
-devices — the CI multidevice job).
+telemetry invariant, the DCT update against a dense float64 reference
+computed in the test, and a reduced ZeRO-1 parity check per backend (8
+forced host devices — the CI multidevice job).
 """
 import dataclasses
 import functools
@@ -250,34 +250,64 @@ def test_fused_matches_reference_new_backends(kind, shape):
 
 
 # ---------------------------------------------------------------------------
-# DCT bit-identity pin (pre-refactor golden digests)
+# DCT parity against a dense float64 reference computed in the test
 # ---------------------------------------------------------------------------
-# Recorded from the hardcoded-dct implementation at PR-4 head (commit
-# 0bbcf75): per fused mode and shape, [sum(d_t) for t=1..3] +
-# [sum(|d_t|) for t=1..3] of the rank-8 q8-EF T_u=2 update, each reduced
-# in float64 and cast to fp32. Bitwise-identical updates <=> identical
-# digests; any numeric drift in the refactored dct path trips this.
-_DCT_GOLDEN = {
-    ("off", "2d"): [-1.8221326172351837e-06, -4.248169716447592e-06, -43.813323974609375, 449.09912109375, 316.1246643066406, 283.2120666503906],
-    ("off", "stacked"): [-19.595788955688477, -4.822482585906982, 6.259047985076904, 1346.761474609375, 930.4658203125, 858.7440185546875],
-    ("off", "odd"): [-5.448237061500549e-08, 1.3905810192227364e-06, -7.9016594886779785, 310.8307189941406, 212.29336547851562, 195.54966735839844],
-    ("off", "transposed"): [-1.8891296349465847e-06, -1.1588454071898013e-06, -25.552444458007812, 448.7791748046875, 316.75274658203125, 277.8719177246094],
-    ("on", "2d"): [-1.8221326172351837e-06, -4.248169716447592e-06, -43.813323974609375, 449.09912109375, 316.1246643066406, 283.2120666503906],
-    ("on", "stacked"): [-19.595788955688477, -4.822482585906982, 6.259047985076904, 1346.761474609375, 930.4658203125, 858.7440185546875],
-    ("on", "odd"): [-5.448237061500549e-08, 1.3905810192227364e-06, -7.9016594886779785, 310.8307189941406, 212.29336547851562, 195.54966735839844],
-    ("on", "transposed"): [-1.8891296349465847e-06, -1.1588454071898013e-06, -25.552444458007812, 448.7791748046875, 316.75274658203125, 277.8719177246094],
-    ("fft", "2d"): [-4.7637149691581726e-07, -4.7245994210243225e-06, -43.813323974609375, 449.09912109375, 316.1246643066406, 283.2120666503906],
-    ("fft", "stacked"): [-19.59578514099121, -4.822486400604248, 6.259049892425537, 1346.7613525390625, 930.4658203125, 858.7440185546875],
-    ("fft", "odd"): [-3.421446308493614e-07, 1.598498784005642e-06, -7.901658535003662, 310.8307189941406, 212.29336547851562, 195.54965209960938],
-    ("fft", "transposed"): [-4.318950232118368e-06, -7.642402124474756e-07, -25.55244255065918, 448.7791748046875, 316.75274658203125, 277.8719177246094],
-}
 _PIN_SHAPES = {"2d": (24, 40), "stacked": (3, 24, 40), "odd": (33, 17),
                "transposed": (16, 48)}
+# float32 update vs the float64 reference: the projections round at about
+# 1e-7 relative and Adam's normalisation keeps update entries near unit
+# size, so 1e-4 covers the rounding with room and still catches a wrong
+# column, a missed rotation or a stale error-feedback buffer
+_DCT_REF_RTOL, _DCT_REF_ATOL = 1e-4, 1e-4
+
+
+def _dense_dct_adamw(grads, *, rank, interval, b1=0.9, b2=0.999, eps=1e-8):
+    """DCT-AdamW with int8 error feedback, written from the algorithm in
+    optim/projected_adam.py with a dense orthonormal DCT-II matrix in
+    float64. Returns the update of every step."""
+    shape = grads[0].shape
+    transposed = shape[-1] > shape[-2]
+    rows, n = max(shape[-2:]), min(shape[-2:])
+    batch = shape[:-2]
+    k = np.arange(n)[:, None]
+    j = np.arange(n)[None, :]
+    q = np.sqrt(2.0 / n) * np.cos(np.pi * (2 * k + 1) * j / (2 * n))
+    q[:, 0] /= np.sqrt(2.0)                  # column j = frequency j
+    r = min(rank, n)
+    m = np.zeros((*batch, rows, r))
+    v = np.zeros_like(m)
+    idx = np.broadcast_to(np.arange(r), (*batch, r))
+    ef = np.zeros((*batch, rows, n))         # dequantised int8 EF buffer
+    tiny = np.finfo(np.float32).tiny
+    outs = []
+    for t, g in enumerate(grads, start=1):
+        gf = np.asarray(g, np.float64)
+        gf = (np.swapaxes(gf, -1, -2) if transposed else gf) + ef
+        if t == 1 or t % interval == 1:      # refresh: top-r column energy
+            norms = ((gf @ q) ** 2).sum(axis=-2)
+            new = np.sort(np.argsort(-norms, axis=-1)[..., :r], axis=-1)
+            rot = (idx[..., :, None] == new[..., None, :]).astype(np.float64)
+            m, v, idx = m @ rot, np.abs(v @ rot), new
+        qr = np.swapaxes(q.T[idx], -1, -2)   # (..., n, r) selected columns
+        g_low = gf @ qr
+        m = b1 * m + (1 - b1) * g_low
+        v = b2 * v + (1 - b2) * g_low ** 2
+        u = (m / (1 - b1 ** t)) / (np.sqrt(v / (1 - b2 ** t)) + eps)
+        d = u @ np.swapaxes(qr, -1, -2)
+        resid = gf - g_low @ np.swapaxes(qr, -1, -2)
+        scale = np.maximum(np.abs(resid).max(axis=-1, keepdims=True) / 127,
+                           tiny)
+        ef = np.clip(np.round(resid / scale), -127, 127) * scale
+        outs.append(np.swapaxes(d, -1, -2) if transposed else d)
+    return outs
 
 
 @pytest.mark.parametrize("mode", ["off", "on", "fft"])
 @pytest.mark.parametrize("shape_id", list(_PIN_SHAPES))
 def test_dct_bit_identical_to_pre_refactor(mode, shape_id):
+    """The rank-8, int8-EF, T_u=2 DCT update of every fused mode matches
+    the dense float64 reference over three steps (refresh, keep,
+    refresh)."""
     shape = _PIN_SHAPES[shape_id]
     rule = ProjectedAdamRule(rank=8, projector="dct", residual="ef",
                              ef_dtype="q8", update_interval=2, fused=mode)
@@ -290,18 +320,15 @@ def test_dct_bit_identical_to_pre_refactor(mode, shape_id):
         ctx = Context(step=step, bases={}, key=jax.random.PRNGKey(7))
         return rule.update(g, state, param, ctx)
 
-    sums, abssums = [], []
-    for t in range(1, 4):
-        g = jnp.asarray(rng.standard_normal(shape), jnp.float32)
-        d, state = step_fn(g, state, jnp.asarray(t, jnp.int32))
-        d = np.asarray(d)
-        sums.append(float(np.float32(d.astype(np.float64).sum())))
-        abssums.append(float(np.float32(np.abs(d).astype(np.float64).sum())))
-    np.testing.assert_array_equal(
-        np.asarray(sums + abssums, np.float64),
-        np.asarray(_DCT_GOLDEN[(mode, shape_id)], np.float64),
-        err_msg=f"dct update drifted from pre-refactor outputs "
-                f"({mode}/{shape_id})")
+    grads = [rng.standard_normal(shape).astype(np.float32) for _ in range(3)]
+    want = _dense_dct_adamw(grads, rank=8, interval=2)
+    for t, g in enumerate(grads, start=1):
+        d, state = step_fn(jnp.asarray(g), state, jnp.asarray(t, jnp.int32))
+        np.testing.assert_allclose(
+            np.asarray(d), want[t - 1], rtol=_DCT_REF_RTOL,
+            atol=_DCT_REF_ATOL,
+            err_msg=f"dct update differs from the dense reference "
+                    f"({mode}/{shape_id}, step {t})")
 
 
 # ---------------------------------------------------------------------------
@@ -360,12 +387,11 @@ def test_basis_cache_serves_all_kinds():
                            "8 host devices via XLA_FLAGS)")
 @pytest.mark.parametrize("kind", ["dst", "hadamard", "randortho"])
 def test_zero_parity_new_backends_multidevice(kind):
-    """Sharded vs replicated updates bit-identical (fp32) for every
+    """Sharded vs replicated updates equal to fp32 rounding for every
     non-dct backend — the reduced companion of tests/test_zero_parity.py
     (which pins dct exhaustively)."""
     from repro.launch.mesh import make_mesh
     from repro.optim.transform import matrix_optimizer
-    from repro.parallel.compat import set_mesh
     from repro.parallel.zero import ZeroConfig
 
     rule = ProjectedAdamRule(rank=8, projector=kind, residual="ef",
@@ -379,10 +405,10 @@ def test_zero_parity_new_backends_multidevice(kind):
     zo = matrix_optimizer(rule, 1e-2, zero=ZeroConfig(mode="1",
                                                       axes=("data",)))
     u_rep, _ = jax.jit(rep.update)(grads, rep.init(params), params)
-    with set_mesh(make_mesh((8,), ("data",))):
+    with jax.set_mesh(make_mesh((8,), ("data",))):
         u_z, _ = jax.jit(zo.update)(grads, zo.init(params), params)
-    a = np.asarray(u_rep["w"])
-    b = np.asarray(jax.device_get(u_z["w"]))
-    assert a.tobytes() == b.tobytes(), \
-        f"{kind}: sharded update differs from replicated (max " \
-        f"{np.abs(a - b).max()})"
+    # fp32 rounding: the row blocks' reductions run in another order
+    np.testing.assert_allclose(
+        np.asarray(jax.device_get(u_z["w"])), np.asarray(u_rep["w"]),
+        rtol=1e-5, atol=1e-7,
+        err_msg=f"{kind}: sharded update differs from replicated")

@@ -192,12 +192,31 @@ def test_select_and_project_is_single_pass(monkeypatch):
                                np.asarray(gf @ qr), atol=2e-5, rtol=1e-5)
 
 
+def test_imports_take_no_backend():
+    """Importing the package, the optimizer, the kernels, the serving layer
+    or the training CLI initialises no JAX backend: a parent process (the
+    restart supervisor) must leave the chip to its child."""
+    import os
+    import subprocess
+    import sys
+
+    code = ("import repro, repro.optim, repro.kernels, repro.serve, "
+            "repro.launch.train, repro.train.supervisor\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized()\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"))
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr
+
+
 def test_resolve_modes():
     assert fused_step.resolve("off") == "off"
     assert fused_step.resolve("on") == "on"
     assert fused_step.resolve("fft") == "fft"
     # auto degrades to the reference path off-TPU
-    expected = "on" if fused_step.ops.ON_TPU else "off"
+    expected = "on" if fused_step.ops.on_tpu() else "off"
     assert fused_step.resolve("auto") == expected
     fused_step.set_default_fused_mode("fft")
     try:

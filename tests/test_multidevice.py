@@ -21,7 +21,6 @@ _SCRIPT = textwrap.dedent("""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.launch.mesh import make_mesh
-    from repro.parallel.compat import set_mesh
     from repro.models.layers import blockwise_attention, sp_blockwise_attention
     from repro.models.config import ModelConfig
     from repro.models import transformer as T
@@ -37,7 +36,7 @@ _SCRIPT = textwrap.dedent("""
     q = jnp.asarray(rng.standard_normal((b, s, hq, hd)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((b, s, hkv, hd)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((b, s, hkv, hd)), jnp.float32)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         ref = jax.jit(lambda q, k, v: blockwise_attention(
             q, k, v, causal=True, q_chunk=16, kv_chunk=16))(q, k, v)
         out = jax.jit(lambda q, k, v: sp_blockwise_attention(
@@ -60,7 +59,7 @@ _SCRIPT = textwrap.dedent("""
     losses = {}
     for sp in (False, True):
         c = dataclasses.replace(cfg, attn_sp=sp)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = init_state(c, opt, jax.random.PRNGKey(0))
             _, m = jax.jit(make_train_step(c, opt))(state, batch)
             losses[sp] = float(m["loss"])
@@ -70,7 +69,7 @@ _SCRIPT = textwrap.dedent("""
     # ---- 3. layout policy loss parity -------------------------------------
     vals = {}
     for layout in ("fsdp_tp", "pure_dp"):
-        with sh.use_policy(layout=layout), set_mesh(mesh):
+        with sh.use_policy(layout=layout), jax.set_mesh(mesh):
             state = init_state(cfg, opt, jax.random.PRNGKey(0))
             _, m = jax.jit(make_train_step(cfg, opt))(state, batch)
             vals[layout] = float(m["loss"])
@@ -89,7 +88,7 @@ _SCRIPT = textwrap.dedent("""
     tok = jnp.asarray(rng.integers(0, 64, (4,)), jnp.int32)
     outs = {}
     for layout in ("fsdp_tp", "decode_tp"):
-        with sh.use_policy(layout=layout), set_mesh(mesh):
+        with sh.use_policy(layout=layout), jax.set_mesh(mesh):
             cache = T.init_cache(moe_cfg, 4, 16)
             lg, _ = jax.jit(
                 lambda p, c, t: T.decode_step(p, c, t, jnp.int32(0), moe_cfg)

@@ -36,8 +36,7 @@ def test_scan_multiplies_trip_count():
     c = module_costs(compiled.as_text())
     expect = 2 * n**3 * L
     assert 0.4 * expect <= c.flops <= 3 * expect, (c.flops, expect)
-    from repro.parallel.compat import cost_analysis
-    xla = cost_analysis(compiled).get("flops", 0.0)
+    xla = compiled.cost_analysis().get("flops", 0.0)
     # document the discrepancy this model exists to fix
     assert xla < 0.5 * expect, "XLA now counts trips; revisit hlo_cost"
 
@@ -71,3 +70,16 @@ def test_collective_parser_shapes():
     stats = collective_bytes(txt)
     assert stats["all-gather"]["bytes"] == 128 * 256 * 4
     assert stats["all-reduce"]["bytes"] == 2 * 64 * 2
+
+
+def test_arch_from_tpu_device_kind():
+    """A TPU's ``device_kind`` picks its arch; an unknown kind is an
+    error, not a silent v5e default."""
+    import pytest
+
+    from repro.roofline import hw
+
+    assert hw.arch_for_device_kind("TPU v5 lite") == "v5e"
+    assert hw.get_arch(hw.arch_for_device_kind("TPU v5")).name == "v5p"
+    with pytest.raises(ValueError, match="unknown TPU device_kind"):
+        hw.arch_for_device_kind("TPU v9 imaginary")

@@ -337,29 +337,34 @@ def test_int8_kernel_matches_mirror(gshape, n):
 
 
 def test_int8_colgather_matches_mirror():
+    """Matches the jnp mirror, also at a width that is not a multiple of
+    the kernel's four-row int8 packing."""
     from repro.kernels import colgather_matmul, colgather_matmul_dual
     from repro.kernels.lowp import lowp_gather_matmul
 
-    b = _rand((2, 40, 8), seed=17)
-    q = dct2_matrix(48)
-    qt = jnp.swapaxes(q, -1, -2)
-    idx = jnp.stack([jnp.arange(8), jnp.arange(8) * 3 % 48]).astype(jnp.int32)
-    out_k = colgather_matmul(b, qt, idx, block=(32, 32), interpret=True,
-                             compute_dtype="int8")
-    (out_m,) = lowp_gather_matmul((b,), qt, idx, "int8")
-    np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_m),
-                               rtol=1e-5, atol=1e-5)
-    b2 = _rand((2, 40, 8), seed=19)
-    d_k = colgather_matmul_dual(b, b2, qt, idx, block=(32, 32),
-                                interpret=True, compute_dtype="int8")
-    d_m = lowp_gather_matmul((b, b2), qt, idx, "int8")
-    for got, want in zip(d_k, d_m):
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+    for n in (48, 50):
+        b = _rand((2, 40, 8), seed=17)
+        q = dct2_matrix(n)
+        qt = jnp.swapaxes(q, -1, -2)
+        idx = jnp.stack([jnp.arange(8), jnp.arange(8) * 3 % n + 1]
+                        ).astype(jnp.int32)
+        out_k = colgather_matmul(b, qt, idx, block=(32, 32), interpret=True,
+                                 compute_dtype="int8")
+        (out_m,) = lowp_gather_matmul((b,), qt, idx, "int8")
+        np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_m),
                                    rtol=1e-5, atol=1e-5)
-    # and the fp32 back-projection ground truth stays within the int8 bound
-    ref = jnp.einsum("bmr,brn->bmn", b, jnp.take(qt, idx, axis=0))
-    rel = float(jnp.linalg.norm(out_k - ref) / jnp.linalg.norm(ref))
-    assert rel <= LOWP_ERROR_BOUNDS["int8"]
+        b2 = _rand((2, 40, 8), seed=19)
+        d_k = colgather_matmul_dual(b, b2, qt, idx, block=(32, 32),
+                                    interpret=True, compute_dtype="int8")
+        d_m = lowp_gather_matmul((b, b2), qt, idx, "int8")
+        for got, want in zip(d_k, d_m):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=1e-5, atol=1e-5)
+        # and the fp32 back-projection ground truth stays within the int8
+        # bound
+        ref = jnp.einsum("bmr,brn->bmn", b, jnp.take(qt, idx, axis=0))
+        rel = float(jnp.linalg.norm(out_k - ref) / jnp.linalg.norm(ref))
+        assert rel <= LOWP_ERROR_BOUNDS["int8"]
 
 
 @pytest.mark.parametrize("dt", ["bf16", "int8"])
