@@ -143,7 +143,7 @@ The host-side metrics registry, span tracer and exporters (DESIGN.md
         ]),
         ("repro.obs.tracing", ["SpanTracer", "tracer"]),
         ("repro.obs.exporters", [
-            "prometheus_exposition", "PrometheusExporter", "JSONLExporter",
+            "prometheus_exposition", "PrometheusExporter",
         ]),
     ]),
     ("tune.md", "repro.tune — kernel autotuner & low-precision compute", """\

@@ -338,90 +338,120 @@ def _mla_apply(p, x, cfg, *, return_kv=False):
 # Block application (train / prefill)
 # ===========================================================================
 def block_apply(kind: str, p, x, cfg, ctx, *, return_kv=False):
-    """Returns (x_out, aux_scalar, cache_entry_or_None)."""
+    """Returns (x_out, aux_scalar, cache_entry_or_None).
+
+    Each half of the block (pre-norm, its mixer, residual add) runs under
+    a ``model/<part>`` name scope, so every device op of the train step
+    names the layer it belongs to (docs/observability.md, "Device
+    scopes")."""
     aux = jnp.zeros((), jnp.float32)
     kv = None
     if kind in ("attn", "local", "attn_moe"):
-        h = rms_norm(x, p["ln1"]["scale"], cfg.norm_eps)
-        window = cfg.sliding_window if kind == "local" else None
-        a, kv = _gqa_apply(p["attn"], h, cfg, causal=True, window=window,
-                           return_kv=return_kv)
-        x = x + a
-        h = rms_norm(x, p["ln2"]["scale"], cfg.norm_eps)
-        if kind == "attn_moe":
-            m, aux = moe_ffn(p["moe"], h, cfg)
-        else:
-            m = swiglu(h, p["mlp"]["wg"]["kernel"], p["mlp"]["wu"]["kernel"],
-                       p["mlp"]["wd"]["kernel"])
-        x = x + m
+        with jax.named_scope("model/attn"):
+            h = rms_norm(x, p["ln1"]["scale"], cfg.norm_eps)
+            window = cfg.sliding_window if kind == "local" else None
+            a, kv = _gqa_apply(p["attn"], h, cfg, causal=True,
+                               window=window, return_kv=return_kv)
+            x = x + a
+        with jax.named_scope("model/mlp"):
+            h = rms_norm(x, p["ln2"]["scale"], cfg.norm_eps)
+            if kind == "attn_moe":
+                m, aux = moe_ffn(p["moe"], h, cfg)
+            else:
+                m = swiglu(h, p["mlp"]["wg"]["kernel"],
+                           p["mlp"]["wu"]["kernel"], p["mlp"]["wd"]["kernel"])
+            x = x + m
     elif kind in MLA_KINDS:
-        h = rms_norm(x, p["ln1"]["scale"], cfg.norm_eps)
-        a, kv = _mla_apply(p["attn"], h, cfg, return_kv=return_kv)
-        x = x + a
-        h = rms_norm(x, p["ln2"]["scale"], cfg.norm_eps)
-        if kind == "mla_moe":
-            m, aux = moe_ffn(p["moe"], h, cfg)
-        else:
-            m = swiglu(h, p["mlp"]["wg"]["kernel"], p["mlp"]["wu"]["kernel"],
-                       p["mlp"]["wd"]["kernel"])
-        x = x + m
+        with jax.named_scope("model/attn"):
+            h = rms_norm(x, p["ln1"]["scale"], cfg.norm_eps)
+            a, kv = _mla_apply(p["attn"], h, cfg, return_kv=return_kv)
+            x = x + a
+        with jax.named_scope("model/mlp"):
+            h = rms_norm(x, p["ln2"]["scale"], cfg.norm_eps)
+            if kind == "mla_moe":
+                m, aux = moe_ffn(p["moe"], h, cfg)
+            else:
+                m = swiglu(h, p["mlp"]["wg"]["kernel"],
+                           p["mlp"]["wu"]["kernel"], p["mlp"]["wd"]["kernel"])
+            x = x + m
     elif kind in ("mamba_dense", "mamba_moe"):
-        h = rms_norm(x, p["ln1"]["scale"], cfg.norm_eps)
-        if return_kv:
-            mx, kv = mamba_mix(p["mamba"], h, cfg, return_state=True)
-        else:
-            mx = mamba_mix(p["mamba"], h, cfg)
-        x = x + mx
-        h = rms_norm(x, p["ln2"]["scale"], cfg.norm_eps)
-        if kind == "mamba_moe":
-            m, aux = moe_ffn(p["moe"], h, cfg)
-        else:
-            m = swiglu(h, p["mlp"]["wg"]["kernel"], p["mlp"]["wu"]["kernel"],
-                       p["mlp"]["wd"]["kernel"])
-        x = x + m
+        with jax.named_scope("model/ssm"):
+            h = rms_norm(x, p["ln1"]["scale"], cfg.norm_eps)
+            if return_kv:
+                mx, kv = mamba_mix(p["mamba"], h, cfg, return_state=True)
+            else:
+                mx = mamba_mix(p["mamba"], h, cfg)
+            x = x + mx
+        with jax.named_scope("model/mlp"):
+            h = rms_norm(x, p["ln2"]["scale"], cfg.norm_eps)
+            if kind == "mamba_moe":
+                m, aux = moe_ffn(p["moe"], h, cfg)
+            else:
+                m = swiglu(h, p["mlp"]["wg"]["kernel"],
+                           p["mlp"]["wu"]["kernel"], p["mlp"]["wd"]["kernel"])
+            x = x + m
     elif kind == "rwkv":
         b, s, d = x.shape
         hh, hs = cfg.rwkv_n_heads, cfg.rwkv_head_size
-        h = layer_norm(x, p["ln1"]["scale"], p["ln1"]["bias"], cfg.norm_eps)
-        x_prev0 = jnp.zeros((b, d), h.dtype)
-        st0 = jnp.zeros((b, hh, hs, hs), jnp.float32)
-        tm_out, last_x, st = time_mix(p["tm"], h, x_prev0, st0, cfg)
-        x = x + tm_out
-        h2 = layer_norm(x, p["ln2"]["scale"], p["ln2"]["bias"], cfg.norm_eps)
-        cm_out, last_cm = channel_mix(p["cm"], h2, jnp.zeros((b, d), h2.dtype))
-        x = x + cm_out
+        with jax.named_scope("model/rwkv"):
+            h = layer_norm(x, p["ln1"]["scale"], p["ln1"]["bias"],
+                           cfg.norm_eps)
+            x_prev0 = jnp.zeros((b, d), h.dtype)
+            st0 = jnp.zeros((b, hh, hs, hs), jnp.float32)
+            tm_out, last_x, st = time_mix(p["tm"], h, x_prev0, st0, cfg)
+            x = x + tm_out
+        with jax.named_scope("model/mlp"):
+            h2 = layer_norm(x, p["ln2"]["scale"], p["ln2"]["bias"],
+                            cfg.norm_eps)
+            cm_out, last_cm = channel_mix(p["cm"], h2,
+                                          jnp.zeros((b, d), h2.dtype))
+            x = x + cm_out
         if return_kv:
             kv = {"x_prev_tm": last_x, "x_prev_cm": last_cm, "wkv": st}
     elif kind == "cross":
-        h = rms_norm(x, p["ln1"]["scale"], cfg.norm_eps)
-        a, kv = _gqa_apply(p["xattn"], h, cfg, causal=False,
-                           kv_src=ctx["image_embeds"], rope=False,
-                           return_kv=return_kv)
-        x = x + jnp.tanh(p["gate_attn"]) * a
-        h = rms_norm(x, p["ln2"]["scale"], cfg.norm_eps)
-        m = swiglu(h, p["mlp"]["wg"]["kernel"], p["mlp"]["wu"]["kernel"],
-                   p["mlp"]["wd"]["kernel"])
-        x = x + jnp.tanh(p["gate_mlp"]) * m
+        with jax.named_scope("model/xattn"):
+            h = rms_norm(x, p["ln1"]["scale"], cfg.norm_eps)
+            a, kv = _gqa_apply(p["xattn"], h, cfg, causal=False,
+                               kv_src=ctx["image_embeds"], rope=False,
+                               return_kv=return_kv)
+            x = x + jnp.tanh(p["gate_attn"]) * a
+        with jax.named_scope("model/mlp"):
+            h = rms_norm(x, p["ln2"]["scale"], cfg.norm_eps)
+            m = swiglu(h, p["mlp"]["wg"]["kernel"], p["mlp"]["wu"]["kernel"],
+                       p["mlp"]["wd"]["kernel"])
+            x = x + jnp.tanh(p["gate_mlp"]) * m
     elif kind == "enc":
-        h = layer_norm(x, p["ln1"]["scale"], p["ln1"]["bias"], cfg.norm_eps)
-        a, _ = _gqa_apply(p["attn"], h, cfg, causal=False, rope=False)
-        x = x + a
-        h = layer_norm(x, p["ln2"]["scale"], p["ln2"]["bias"], cfg.norm_eps)
-        x = x + gelu_mlp(h, p["mlp"]["wi"]["kernel"], p["mlp"]["wi"]["bias"],
-                         p["mlp"]["wo"]["kernel"], p["mlp"]["wo"]["bias"])
+        with jax.named_scope("model/attn"):
+            h = layer_norm(x, p["ln1"]["scale"], p["ln1"]["bias"],
+                           cfg.norm_eps)
+            a, _ = _gqa_apply(p["attn"], h, cfg, causal=False, rope=False)
+            x = x + a
+        with jax.named_scope("model/mlp"):
+            h = layer_norm(x, p["ln2"]["scale"], p["ln2"]["bias"],
+                           cfg.norm_eps)
+            x = x + gelu_mlp(h, p["mlp"]["wi"]["kernel"],
+                             p["mlp"]["wi"]["bias"], p["mlp"]["wo"]["kernel"],
+                             p["mlp"]["wo"]["bias"])
     elif kind == "dec":
-        h = layer_norm(x, p["ln1"]["scale"], p["ln1"]["bias"], cfg.norm_eps)
-        a, kv_self = _gqa_apply(p["attn"], h, cfg, causal=True,
-                                return_kv=return_kv)
-        x = x + a
-        h = layer_norm(x, p["ln2"]["scale"], p["ln2"]["bias"], cfg.norm_eps)
-        a, kv_cross = _gqa_apply(p["xattn"], h, cfg, causal=False,
-                                 kv_src=ctx["enc_out"], rope=False,
-                                 return_kv=return_kv)
-        x = x + a
-        h = layer_norm(x, p["ln3"]["scale"], p["ln3"]["bias"], cfg.norm_eps)
-        x = x + gelu_mlp(h, p["mlp"]["wi"]["kernel"], p["mlp"]["wi"]["bias"],
-                         p["mlp"]["wo"]["kernel"], p["mlp"]["wo"]["bias"])
+        with jax.named_scope("model/attn"):
+            h = layer_norm(x, p["ln1"]["scale"], p["ln1"]["bias"],
+                           cfg.norm_eps)
+            a, kv_self = _gqa_apply(p["attn"], h, cfg, causal=True,
+                                    return_kv=return_kv)
+            x = x + a
+        with jax.named_scope("model/xattn"):
+            h = layer_norm(x, p["ln2"]["scale"], p["ln2"]["bias"],
+                           cfg.norm_eps)
+            a, kv_cross = _gqa_apply(p["xattn"], h, cfg, causal=False,
+                                     kv_src=ctx["enc_out"], rope=False,
+                                     return_kv=return_kv)
+            x = x + a
+        with jax.named_scope("model/mlp"):
+            h = layer_norm(x, p["ln3"]["scale"], p["ln3"]["bias"],
+                           cfg.norm_eps)
+            x = x + gelu_mlp(h, p["mlp"]["wi"]["kernel"],
+                             p["mlp"]["wi"]["bias"], p["mlp"]["wo"]["kernel"],
+                             p["mlp"]["wo"]["bias"])
         kv = (kv_self, kv_cross) if return_kv else None
     else:
         raise ValueError(kind)
@@ -450,7 +480,8 @@ def encode(params, frames, cfg):
 
     if cfg.remat:
         body = jax.checkpoint(body)
-    x, _ = jax.lax.scan(body, x, params["encoder"]["blocks"])
+    with jax.named_scope("model/blocks"):
+        x, _ = jax.lax.scan(body, x, params["encoder"]["blocks"])
     ln = params["encoder"]["ln_post"]
     return layer_norm(x, ln["scale"], ln["bias"], cfg.norm_eps)
 
@@ -464,15 +495,18 @@ def forward(params, batch, cfg, *, return_cache: bool = False):
     return_cache, (logits, aux, cache)."""
     tokens = batch["tokens"]
     cdt = jnp.dtype(cfg.compute_dtype)
-    params = cast_params(params, cfg)
-    x = params["embed"]["kernel"][tokens]
-    x = shard(x, "batch", "sp", None)
+    with jax.named_scope("model/embed"):
+        params = cast_params(params, cfg)
+        x = params["embed"]["kernel"][tokens]
+        x = shard(x, "batch", "sp", None)
 
     ctx = {}
     if cfg.encoder_layers:
-        ctx["enc_out"] = encode(params, batch["frames"], cfg)
+        with jax.named_scope("model/encoder"):
+            ctx["enc_out"] = encode(params, batch["frames"], cfg)
     if cfg.n_image_tokens:
-        ctx["image_embeds"] = batch["image_embeds"].astype(cdt)
+        with jax.named_scope("model/embed"):
+            ctx["image_embeds"] = batch["image_embeds"].astype(cdt)
 
     aux_total = jnp.zeros((), jnp.float32)
     caches = []
@@ -492,35 +526,42 @@ def forward(params, batch, cfg, *, return_cache: bool = False):
 
         if cfg.remat:
             body = jax.checkpoint(body)
-        (x, aux_total), seg_cache = jax.lax.scan(body, (x, aux_total), seg)
+        # the scan's own work (slicing each layer's weights out of the
+        # stack, stacking residuals and gradients) lands in model/blocks
+        with jax.named_scope("model/blocks"):
+            (x, aux_total), seg_cache = jax.lax.scan(body, (x, aux_total),
+                                                     seg)
         caches.append(seg_cache)
 
-    if cfg.family == "encdec":
-        fn = params["final_norm"]
-        x = layer_norm(x, fn["scale"], fn["bias"], cfg.norm_eps)
-    else:
-        x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
+    with jax.named_scope("model/head"):
+        if cfg.family == "encdec":
+            fn = params["final_norm"]
+            x = layer_norm(x, fn["scale"], fn["bias"], cfg.norm_eps)
+        else:
+            x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
 
-    unemb = (params["embed"] if cfg.tie_embeddings else params["unembed"])
-    logits = x @ unemb["kernel"].astype(cdt).T
-    from repro.parallel.sharding import seq_parallel as _seq_par
-    if _seq_par():
-        logits = shard(logits, "batch", "sp", None)
-    else:
-        logits = shard(logits, "batch", None, "tp")
+        unemb = (params["embed"] if cfg.tie_embeddings else params["unembed"])
+        logits = x @ unemb["kernel"].astype(cdt).T
+        from repro.parallel.sharding import seq_parallel as _seq_par
+        if _seq_par():
+            logits = shard(logits, "batch", "sp", None)
+        else:
+            logits = shard(logits, "batch", None, "tp")
 
-    aux = {"moe_aux": aux_total, "mtp_logits": None}
-    if cfg.mtp and "mtp" in params:
-        # DeepSeek-style multi-token prediction: predict t+2 from
-        # [h_t ; embed(token_{t+1})]. Full-length with a roll (position S-1
-        # is masked in the loss) so the gather keeps the (B, S) sharding —
-        # a [:, 1:] slice makes S odd and forces SPMD to replicate the
-        # embedding table (XLA "involuntary full rematerialization").
-        emb_next = params["embed"]["kernel"][jnp.roll(tokens, -1, axis=1)]
-        h_mtp = jnp.concatenate([x, emb_next], axis=-1)
-        h_mtp = h_mtp @ params["mtp"]["proj"]["kernel"].astype(cdt)
-        h_mtp = rms_norm(h_mtp, params["mtp"]["norm"]["scale"], cfg.norm_eps)
-        aux["mtp_logits"] = h_mtp @ unemb["kernel"].astype(cdt).T
+        aux = {"moe_aux": aux_total, "mtp_logits": None}
+        if cfg.mtp and "mtp" in params:
+            # DeepSeek-style multi-token prediction: predict t+2 from
+            # [h_t ; embed(token_{t+1})]. Full-length with a roll (position
+            # S-1 is masked in the loss) so the gather keeps the (B, S)
+            # sharding — a [:, 1:] slice makes S odd and forces SPMD to
+            # replicate the embedding table (XLA "involuntary full
+            # rematerialization").
+            emb_next = params["embed"]["kernel"][jnp.roll(tokens, -1, axis=1)]
+            h_mtp = jnp.concatenate([x, emb_next], axis=-1)
+            h_mtp = h_mtp @ params["mtp"]["proj"]["kernel"].astype(cdt)
+            h_mtp = rms_norm(h_mtp, params["mtp"]["norm"]["scale"],
+                             cfg.norm_eps)
+            aux["mtp_logits"] = h_mtp @ unemb["kernel"].astype(cdt).T
 
     if return_cache:
         return logits, aux, caches, ctx

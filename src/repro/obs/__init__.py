@@ -10,9 +10,10 @@ change a traced graph — pinned by tests/test_obs.py):
   tracing.py    :class:`SpanTracer` — nested host spans + instant events
                 into a fixed ring buffer; exports Chrome ``trace_event``
                 JSON and step-bucketed JSONL through the existing
-                :class:`repro.telemetry.sink.TelemetrySink`.
+                :class:`repro.telemetry.sink.TelemetrySink`; an enabled
+                span is also a ``jax.profiler`` host annotation.
   exporters.py  Prometheus text-exposition snapshot file (atomic
-                replace) + JSONL snapshot appender.
+                replace).
 
 Observability is **opt-in and process-wide**: everything starts disabled
 and every instrumented call site costs one attribute test until
@@ -34,15 +35,14 @@ Typical use::
 """
 from __future__ import annotations
 
-from .exporters import (JSONLExporter, PrometheusExporter,
-                        prometheus_exposition)
+from .exporters import PrometheusExporter, prometheus_exposition
 from .metrics import (DEFAULT_BUCKETS, Counter, Gauge, Histogram,
                       MetricsRegistry, registry)
 from .tracing import SpanTracer, tracer
 
 __all__ = [
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "DEFAULT_BUCKETS",
-    "SpanTracer", "PrometheusExporter", "JSONLExporter",
+    "SpanTracer", "PrometheusExporter",
     "prometheus_exposition", "registry", "tracer",
     "enable", "disable", "enabled", "reset",
     "write_prometheus", "write_chrome_trace",

@@ -1,8 +1,8 @@
-"""Exporters: Prometheus text exposition + JSONL metric snapshots.
+"""Exporter: Prometheus text exposition of metric snapshots.
 
-Both exporters read :meth:`MetricsRegistry.snapshot` — the instruments'
-hot path never formats strings; all naming/escaping happens here, at
-export cadence (end of a run, every N steps, on demand).
+It reads :meth:`MetricsRegistry.snapshot` — the instruments' hot path
+never formats strings; all naming/escaping happens here, at export
+cadence (end of a run, every N steps, on demand).
 
 :func:`prometheus_exposition` renders the standard text format
 (``# HELP`` / ``# TYPE`` lines, ``{label="value"}`` series, histogram
@@ -11,16 +11,11 @@ export cadence (end of a run, every N steps, on demand).
 (``.tmp`` + rename) so a scraper reading the snapshot file never sees a
 torn write — the file-based equivalent of a ``/metrics`` endpoint for a
 batch process.
-
-:class:`JSONLExporter` appends one JSON object per ``write()`` call —
-a timestamped full snapshot — giving a replayable metric history.
 """
 from __future__ import annotations
 
-import json
 import os
 import re
-import time
 
 from .metrics import MetricsRegistry
 
@@ -95,46 +90,4 @@ class PrometheusExporter:
         with open(tmp, "w") as f:
             f.write(prometheus_exposition(self.registry))
         os.replace(tmp, self.path)
-        return self.path
-
-
-class JSONLExporter:
-    """Appends one timestamped registry snapshot per ``write()`` call.
-
-    Histogram series are exported with their raw bucket counts plus the
-    derived p50/p90/p99 so downstream consumers don't need the edges
-    logic; tuple label keys become ``|``-joined strings (JSON objects
-    need string keys)."""
-
-    def __init__(self, registry: MetricsRegistry, path: str):
-        self.registry = registry
-        self.path = path
-        d = os.path.dirname(path)
-        if d:
-            os.makedirs(d, exist_ok=True)
-
-    def _jsonable(self) -> dict:
-        out: dict = {}
-        for name, snap in self.registry.snapshot().items():
-            entry = {k: v for k, v in snap.items() if k != "series"}
-            series = {}
-            for lv, v in snap["series"].items():
-                key = "|".join(str(x) for x in lv) if lv else ""
-                if snap["type"] == "histogram":
-                    hist = self.registry.get(name)
-                    v = dict(v)
-                    v["p50"] = hist.quantile(0.5, lv)
-                    v["p90"] = hist.quantile(0.9, lv)
-                    v["p99"] = hist.quantile(0.99, lv)
-                series[key] = v
-            entry["series"] = series
-            out[name] = entry
-        return out
-
-    def write(self, *, step: int | None = None) -> str:
-        rec = {"time": time.time(), "metrics": self._jsonable()}
-        if step is not None:
-            rec["step"] = step
-        with open(self.path, "a") as f:
-            f.write(json.dumps(rec) + "\n")
         return self.path

@@ -3,8 +3,13 @@
 A *span* is one timed region of host code (a decode step, a prefill, a
 checkpoint write, a train-loop phase); spans nest through a per-thread
 stack, so a ``ckpt/write`` span opened inside a ``train/step`` span
-records its parent depth. An *event* is a zero-duration instant (a guard
-trip, a rank reallocation) carrying structured args.
+records its parent depth. An enabled span is also a
+``jax.profiler.TraceAnnotation`` of the same name for its lifetime, so
+it appears on the ``/host:CPU`` plane of any ``jax.profiler`` trace, on
+the same clock as the device ops (a host annotation, never a
+``named_scope``: the traced graph does not change when obs toggles).
+An *event* is a zero-duration instant (a guard trip, a rank
+reallocation) carrying structured args.
 
 The buffer is a preallocated list written by a monotonically increasing
 cursor (index = ``seq % capacity``) — append is one slot store + one
@@ -53,7 +58,8 @@ _NOOP = _NoopSpan()
 class _Span:
     """A live span handle; records on ``__exit__``."""
 
-    __slots__ = ("tracer", "name", "cat", "step", "args", "t0", "depth")
+    __slots__ = ("tracer", "name", "cat", "step", "args", "t0", "depth",
+                 "annotation")
 
     def __init__(self, tracer, name, cat, step, args):
         self.tracer = tracer
@@ -66,11 +72,16 @@ class _Span:
         tls = self.tracer._tls
         self.depth = getattr(tls, "depth", 0)
         tls.depth = self.depth + 1
+        from jax.profiler import TraceAnnotation   # jax only once enabled
+
+        self.annotation = TraceAnnotation(self.name)
+        self.annotation.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter_ns() - self.t0
+        self.annotation.__exit__(*exc)
         self.tracer._tls.depth = self.depth
         self.tracer._record({
             "ph": "X", "name": self.name, "cat": self.cat,

@@ -218,8 +218,12 @@ class ProjectedAdamRule(MatrixRule):
                 f"projector={self.projector!r}); pass fused='on'/'fft' with "
                 "a registered basis backend")
 
+        # name scopes (docs/observability.md, "Device scopes"): the
+        # caller's opt/update holds the orientation and casts; each phase
+        # below names its own device ops
         if state.ef is not None:
-            gf = fused_step.ef_add(gf, state.ef, mode=mode)
+            with jax.named_scope("opt/ef"):
+                gf = fused_step.ef_add(gf, state.ef, mode=mode)
 
         def eye_rot():
             eye = jnp.eye(r, dtype=jnp.float32)
@@ -274,35 +278,40 @@ class ProjectedAdamRule(MatrixRule):
             # refresh folds selection AND projection into one pass over G:
             # g_low falls out of S (Alg. 1 line 8), so both branches return it
             def refresh(_):
-                sp = fused_step.select_and_project(
-                    gf, q, r, norm=self.ranking_norm, mode=mode,
-                    return_norms=want_stats, psum_axes=ctx.axis,
-                    backend=backend, compute_dtype=self.compute_dtype)
+                with jax.named_scope("opt/select_project"):
+                    sp = fused_step.select_and_project(
+                        gf, q, r, norm=self.ranking_norm, mode=mode,
+                        return_norms=want_stats, psum_axes=ctx.axis,
+                        backend=backend, compute_dtype=self.compute_dtype)
                 new_proj, g_low = sp[0], sp[1]
                 out = (new_proj, g_low)
                 if self.rotate:
-                    rot = rotation_matrix(state.proj, new_proj, p, cols,
-                                          shared_q=q,
-                                          exact_matmul=self.exact_rotation_matmul)
+                    with jax.named_scope("opt/adam"):
+                        rot = rotation_matrix(
+                            state.proj, new_proj, p, cols, shared_q=q,
+                            exact_matmul=self.exact_rotation_matmul)
                     out = (new_proj, rot, g_low)
                 return out + ((refresh_aux(new_proj, sp[2]),) if want_stats
                               else ())
 
             def keep(_):
-                g_low = fused_step.project_with_indices(
-                    gf, q, state.proj, compute_dtype=self.compute_dtype)
+                with jax.named_scope("opt/select_project"):
+                    g_low = fused_step.project_with_indices(
+                        gf, q, state.proj, compute_dtype=self.compute_dtype)
                 out = ((state.proj, g_low) if not self.rotate
                        else (state.proj, eye_rot(), g_low))
                 return out + ((keep_aux(g_low),) if want_stats else ())
         else:
             def refresh(_):
-                new_proj = p.update(gf, state.proj, shared_q=q, key=ctx.key,
-                                    psum_axes=ctx.axis)
+                with jax.named_scope("opt/select_project"):
+                    new_proj = p.update(gf, state.proj, shared_q=q,
+                                        key=ctx.key, psum_axes=ctx.axis)
                 out = (new_proj,)
                 if self.rotate:
-                    rot = rotation_matrix(state.proj, new_proj, p, cols,
-                                          shared_q=q,
-                                          exact_matmul=self.exact_rotation_matmul)
+                    with jax.named_scope("opt/adam"):
+                        rot = rotation_matrix(
+                            state.proj, new_proj, p, cols, shared_q=q,
+                            exact_matmul=self.exact_rotation_matmul)
                     out = (new_proj, rot)
                 return out + ((refresh_aux(new_proj, None),) if want_stats
                               else ())
@@ -323,43 +332,49 @@ class ProjectedAdamRule(MatrixRule):
         if fused:
             g_low = out[2 if self.rotate else 1]                # (..., rows, r)
         else:
-            g_low = p.project(gf, proj_state, shared_q=q)       # (..., rows, r)
+            with jax.named_scope("opt/select_project"):
+                g_low = p.project(gf, proj_state, shared_q=q)   # (.., rows, r)
 
-        if self.rotate:
-            rot = out[1]
-            m_prev = jnp.einsum("...mr,...rs->...ms", state.m, rot)
-            v_prev = jnp.abs(jnp.einsum("...mr,...rs->...ms", state.v, rot))
-        else:
-            m_prev, v_prev = state.m, state.v
-        inner = state.inner_step + 1
-
-        m = self.b1 * m_prev + (1.0 - self.b1) * g_low
-        v = self.b2 * v_prev + (1.0 - self.b2) * g_low * g_low
-        t = inner.astype(jnp.float32)
-        mhat = m / (1.0 - self.b1**t)
-        vhat = v / (1.0 - self.b2**t)
-        u_low = mhat / (jnp.sqrt(vhat) + self.eps)
-
-        if fused:
-            if need_resid:
-                d, recon = fused_step.fused_dual_backproject(
-                    u_low, g_low, q, proj_state, mode=mode,
-                    compute_dtype=self.compute_dtype)
-                resid = gf - recon
+        with jax.named_scope("opt/adam"):
+            if self.rotate:
+                rot = out[1]
+                m_prev = jnp.einsum("...mr,...rs->...ms", state.m, rot)
+                v_prev = jnp.abs(jnp.einsum("...mr,...rs->...ms", state.v,
+                                            rot))
             else:
-                d = fused_step.fused_backproject(
-                    u_low, q, proj_state, mode=mode,
-                    compute_dtype=self.compute_dtype)
-        else:
-            d = p.backproject(u_low, proj_state, shared_q=q, n=cols)
-            if need_resid:
-                resid = gf - p.backproject(g_low, proj_state, shared_q=q,
-                                           n=cols)
+                m_prev, v_prev = state.m, state.v
+            inner = state.inner_step + 1
+
+            m = self.b1 * m_prev + (1.0 - self.b1) * g_low
+            v = self.b2 * v_prev + (1.0 - self.b2) * g_low * g_low
+            t = inner.astype(jnp.float32)
+            mhat = m / (1.0 - self.b1**t)
+            vhat = v / (1.0 - self.b2**t)
+            u_low = mhat / (jnp.sqrt(vhat) + self.eps)
+
+        with jax.named_scope("opt/backproject"):
+            if fused:
+                if need_resid:
+                    d, recon = fused_step.fused_dual_backproject(
+                        u_low, g_low, q, proj_state, mode=mode,
+                        compute_dtype=self.compute_dtype)
+                    resid = gf - recon
+                else:
+                    d = fused_step.fused_backproject(
+                        u_low, q, proj_state, mode=mode,
+                        compute_dtype=self.compute_dtype)
+            else:
+                d = p.backproject(u_low, proj_state, shared_q=q, n=cols)
+                if need_resid:
+                    resid = gf - p.backproject(g_low, proj_state, shared_q=q,
+                                               n=cols)
 
         new_ef = state.ef
         if need_resid:
             if self.residual == "ef":
-                new_ef = fused_step.ef_store(resid, self.ef_dtype, mode=mode)
+                with jax.named_scope("opt/ef"):
+                    new_ef = fused_step.ef_store(resid, self.ef_dtype,
+                                                 mode=mode)
             elif self.residual == "sign":
                 d = d + jnp.sign(resid)                         # FRUGAL state-free
             elif self.residual == "fira":

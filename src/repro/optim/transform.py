@@ -387,10 +387,11 @@ def scale_by_adam(b1: float = 0.9, b2: float = 0.999,
             params)
 
     def update(updates, state, params, ctx):
-        pairs = jax.tree.map(
-            lambda g, s: adam_update(g, s.mom, ctx.step, b1, b2, eps),
-            updates, state,
-            is_leaf=lambda x: isinstance(x, FullAdamLeaf))
+        with jax.named_scope("opt/full_adam"):
+            pairs = jax.tree.map(
+                lambda g, s: adam_update(g, s.mom, ctx.step, b1, b2, eps),
+                updates, state,
+                is_leaf=lambda x: isinstance(x, FullAdamLeaf))
         d = jax.tree.map(lambda g, pr: pr[0], updates, pairs)
         new_state = jax.tree.map(lambda g, pr: FullAdamLeaf(pr[1]),
                                  updates, pairs)

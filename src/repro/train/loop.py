@@ -32,6 +32,7 @@ topology change).
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import signal
 import time
@@ -116,6 +117,14 @@ class Trainer:
         except ValueError:              # not on main thread (tests)
             pass
 
+    def _step_annotation(self, step: int):
+        """The profiler's step marker around one step body while obs is
+        on: loop work outside the phase spans shows in a trace as the
+        step's time less its children."""
+        if not self._tracer.enabled:
+            return contextlib.nullcontext()
+        return jax.profiler.StepTraceAnnotation("train", step_num=step + 1)
+
     def _default_log_metrics(self, record: dict):
         """Console formatter over the structured records — same cadence and
         string as the historic pre-formatted logging."""
@@ -189,95 +198,96 @@ class Trainer:
         step = start
         try:
             while step < total_steps:
-                t0 = time.perf_counter()
-                data_step = step + (res.data_offset if res is not None
-                                    else 0)
-                with self._tracer.span("train/data_wait", step=step + 1):
-                    batch = pipeline.get(data_step)
-                t_data = time.perf_counter()
-                with self._tracer.span("train/dispatch", step=step + 1):
-                    state, metrics = self.train_step(state, batch)
-                t_disp = time.perf_counter()
-                # Timing note: blocking on the loss scalar is the same
-                # sync point the historic float(loss) imposed, so
-                # s_per_step is comparable across versions — but it is
-                # NOT pure compute. It includes the data wait above and
-                # only proves the loss is ready; donated/async outputs of
-                # the step (params, opt state) may still be in flight.
-                # The honest full-state figure is the sampled sync below
-                # (sync_sample_every), exported as
-                # train_full_sync_seconds.
-                with self._tracer.span("train/host_sync", step=step + 1):
-                    jax.block_until_ready(metrics["loss"])
-                dt = time.perf_counter() - t0
-                self._m["data_wait"].observe(t_data - t0)
-                self._m["dispatch"].observe(t_disp - t_data)
-                self._m["host_sync"].observe(max(dt - (t_disp - t0), 0.0))
-                self._m["step_wall"].observe(dt)
-                if self.sync_sample_every > 0 \
-                        and (step + 1) % self.sync_sample_every == 0:
-                    with self._tracer.span("train/full_sync",
-                                           step=step + 1):
-                        jax.block_until_ready(state)
-                    # data-ready -> whole-state-ready: per-step compute
-                    self._m["full_sync"].observe(
-                        time.perf_counter() - t_data)
-                if "telemetry" in metrics and (
-                        self.log_metrics is not None
-                        or self.control_hook is not None):
-                    # one bulk device->host transfer shared by the sink and
-                    # the controllers (instead of per-field fetches twice)
-                    metrics["telemetry"] = jax.device_get(
-                        metrics["telemetry"])
-                committed = True
-                if res is not None:
-                    action = res.observe(
-                        step + 1, float(metrics["loss"]),
-                        bool(metrics.get("all_finite", True)))
-                    if action.reason:
-                        self.log(f"[resilience] {action.kind}: "
-                                 f"{action.reason}")
-                    if action.kind == "skip":
-                        # the guard already refused the update in-jit; the
-                        # optimizer step stands still, the data step moves
-                        # past the offending batch (offset+1 keeps the
-                        # prefetch stream contiguous)
-                        res.skipped()
-                        committed = False
-                        self._m["steps"].inc(1, ("skipped",))
-                    elif action.kind == "rollback":
-                        state, step, pipeline = self._rollback(step,
-                                                               pipeline)
-                        committed = False
-                        self._m["steps"].inc(1, ("rolled_back",))
-                    elif action.kind == "halt":
-                        if self.ckpt is not None:
-                            res.dump(os.path.join(self.ckpt.dir,
-                                                  "halt.json"),
-                                     context={"trainer_step": step})
-                        raise TrainingHalted(action.reason)
-                if committed:
-                    self._m["steps"].inc(1, ("committed",))
-                    # metrics_history keeps scalars only: retaining every
-                    # step's per-leaf stats pytree would grow device memory
-                    # unbounded, and the sink's ring/file persist them
-                    losses.append({k: v for k, v in metrics.items()
-                                   if k != "telemetry"})
-                    self._emit(step + 1, metrics, dt)
-                    if self.control_hook is not None:
-                        new_state = self.control_hook(step + 1, state,
-                                                      metrics)
-                        if new_state is not None:
-                            state = new_state
-                    step += 1
-                if self.ckpt is not None and (
-                        (committed and step % self.ckpt_every == 0)
-                        or self._preempted):
-                    self.ckpt.async_save(step, state,
-                                         extra=self._ckpt_extra())
-                if self._preempted:
-                    self.log("[trainer] SIGTERM -> checkpointed, exiting")
-                    break
+                with self._step_annotation(step):
+                    t0 = time.perf_counter()
+                    data_step = step + (res.data_offset if res is not None
+                                        else 0)
+                    with self._tracer.span("train/data_wait", step=step + 1):
+                        batch = pipeline.get(data_step)
+                    t_data = time.perf_counter()
+                    with self._tracer.span("train/dispatch", step=step + 1):
+                        state, metrics = self.train_step(state, batch)
+                    t_disp = time.perf_counter()
+                    # Timing note: blocking on the loss scalar is the same
+                    # sync point the historic float(loss) imposed, so
+                    # s_per_step is comparable across versions — but it is
+                    # NOT pure compute. It includes the data wait above and
+                    # only proves the loss is ready; donated/async outputs of
+                    # the step (params, opt state) may still be in flight.
+                    # The honest full-state figure is the sampled sync below
+                    # (sync_sample_every), exported as
+                    # train_full_sync_seconds.
+                    with self._tracer.span("train/host_sync", step=step + 1):
+                        jax.block_until_ready(metrics["loss"])
+                    dt = time.perf_counter() - t0
+                    self._m["data_wait"].observe(t_data - t0)
+                    self._m["dispatch"].observe(t_disp - t_data)
+                    self._m["host_sync"].observe(max(dt - (t_disp - t0), 0.0))
+                    self._m["step_wall"].observe(dt)
+                    if self.sync_sample_every > 0 \
+                            and (step + 1) % self.sync_sample_every == 0:
+                        with self._tracer.span("train/full_sync",
+                                               step=step + 1):
+                            jax.block_until_ready(state)
+                        # data-ready -> whole-state-ready: per-step compute
+                        self._m["full_sync"].observe(
+                            time.perf_counter() - t_data)
+                    if "telemetry" in metrics and (
+                            self.log_metrics is not None
+                            or self.control_hook is not None):
+                        # one bulk device->host transfer shared by the sink and
+                        # the controllers (instead of per-field fetches twice)
+                        metrics["telemetry"] = jax.device_get(
+                            metrics["telemetry"])
+                    committed = True
+                    if res is not None:
+                        action = res.observe(
+                            step + 1, float(metrics["loss"]),
+                            bool(metrics.get("all_finite", True)))
+                        if action.reason:
+                            self.log(f"[resilience] {action.kind}: "
+                                     f"{action.reason}")
+                        if action.kind == "skip":
+                            # the guard already refused the update in-jit; the
+                            # optimizer step stands still, the data step moves
+                            # past the offending batch (offset+1 keeps the
+                            # prefetch stream contiguous)
+                            res.skipped()
+                            committed = False
+                            self._m["steps"].inc(1, ("skipped",))
+                        elif action.kind == "rollback":
+                            state, step, pipeline = self._rollback(step,
+                                                                   pipeline)
+                            committed = False
+                            self._m["steps"].inc(1, ("rolled_back",))
+                        elif action.kind == "halt":
+                            if self.ckpt is not None:
+                                res.dump(os.path.join(self.ckpt.dir,
+                                                      "halt.json"),
+                                         context={"trainer_step": step})
+                            raise TrainingHalted(action.reason)
+                    if committed:
+                        self._m["steps"].inc(1, ("committed",))
+                        # metrics_history keeps scalars only: retaining every
+                        # step's per-leaf stats pytree would grow device memory
+                        # unbounded, and the sink's ring/file persist them
+                        losses.append({k: v for k, v in metrics.items()
+                                       if k != "telemetry"})
+                        self._emit(step + 1, metrics, dt)
+                        if self.control_hook is not None:
+                            new_state = self.control_hook(step + 1, state,
+                                                          metrics)
+                            if new_state is not None:
+                                state = new_state
+                        step += 1
+                    if self.ckpt is not None and (
+                            (committed and step % self.ckpt_every == 0)
+                            or self._preempted):
+                        self.ckpt.async_save(step, state,
+                                             extra=self._ckpt_extra())
+                    if self._preempted:
+                        self.log("[trainer] SIGTERM -> checkpointed, exiting")
+                        break
         finally:
             pipeline.close()
             if self.ckpt is not None:

@@ -39,20 +39,22 @@ def _cross_entropy(logits, targets):
 def loss_fn(params, batch, cfg):
     inputs = {k: v for k, v in batch.items() if k != "targets"}
     logits, aux = T.forward(params, inputs, cfg)
-    loss = _cross_entropy(logits, batch["targets"])
-    metrics = {"ce": loss}
-    loss = loss + aux["moe_aux"]
-    if aux.get("mtp_logits") is not None:
-        # MTP head predicts target_{t+1} from position t (DeepSeek-V3);
-        # full-length logits, final position masked (rolled target)
-        mtp_tgt = jnp.roll(batch["targets"], -1, axis=1)
-        logp = jax.nn.log_softmax(aux["mtp_logits"].astype(jnp.float32), -1)
-        nll = -jnp.take_along_axis(logp, mtp_tgt[..., None], -1)[..., 0]
-        s = nll.shape[1]
-        w = (jnp.arange(s) < s - 1).astype(jnp.float32)[None, :]
-        mtp = (nll * w).sum() / w.sum() / nll.shape[0]
-        loss = loss + 0.3 * mtp
-        metrics["mtp_ce"] = mtp
+    with jax.named_scope("model/head"):
+        loss = _cross_entropy(logits, batch["targets"])
+        metrics = {"ce": loss}
+        loss = loss + aux["moe_aux"]
+        if aux.get("mtp_logits") is not None:
+            # MTP head predicts target_{t+1} from position t (DeepSeek-V3);
+            # full-length logits, final position masked (rolled target)
+            mtp_tgt = jnp.roll(batch["targets"], -1, axis=1)
+            logp = jax.nn.log_softmax(
+                aux["mtp_logits"].astype(jnp.float32), -1)
+            nll = -jnp.take_along_axis(logp, mtp_tgt[..., None], -1)[..., 0]
+            s = nll.shape[1]
+            w = (jnp.arange(s) < s - 1).astype(jnp.float32)[None, :]
+            mtp = (nll * w).sum() / w.sum() / nll.shape[0]
+            loss = loss + 0.3 * mtp
+            metrics["mtp_ce"] = mtp
     metrics["loss"] = loss
     return loss, metrics
 
@@ -107,6 +109,11 @@ def make_train_step(cfg, optimizer, *, grad_clip: float = 1.0,
     ``chaos``: a :class:`~repro.train.chaos.ChaosPlan` whose ``grads``
     faults are injected into the traced step, keyed on the data step the
     plan's batch wrapper stamps into each batch (tests/CI only).
+
+    Every device op of the step runs under a ``<layer>/<part>`` name
+    scope (``model/*`` in the model, ``step/*`` here, ``opt/*`` in the
+    optimizer): compile-time metadata that names each op's layer in a
+    profile (docs/observability.md, "Device scopes").
     """
     adt = jnp.dtype(accum_dtype)
 
@@ -122,7 +129,8 @@ def make_train_step(cfg, optimizer, *, grad_clip: float = 1.0,
 
         if n_micro == 1:
             grads, metrics = grad_fn(state.params, batch, cfg)
-            grads = jax.tree.map(lambda g: g.astype(adt), grads)
+            with jax.named_scope("step/clip"):
+                grads = jax.tree.map(lambda g: g.astype(adt), grads)
         else:
             micro = _split_micro(batch, n_micro)
 
@@ -132,38 +140,43 @@ def make_train_step(cfg, optimizer, *, grad_clip: float = 1.0,
                     lambda a, gi: a + (gi / n_micro).astype(adt), acc, g)
                 return acc, m
 
-            zeros = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, adt), state.params)
-            grads, ms = jax.lax.scan(acc_step, zeros, micro)
-            metrics = jax.tree.map(lambda x: x.mean(), ms)
+            with jax.named_scope("step/accum"):
+                zeros = jax.tree.map(
+                    lambda p: jnp.zeros(p.shape, adt), state.params)
+                grads, ms = jax.lax.scan(acc_step, zeros, micro)
+                metrics = jax.tree.map(lambda x: x.mean(), ms)
 
         if chaos is not None and chaos_step is not None:
             grads = chaos.tamper_grads(chaos_step, grads)
 
-        if grad_clip:
-            grads, gnorm = _clip_by_global_norm(grads, grad_clip)
-        else:
-            gnorm = _global_norm(grads)
+        with jax.named_scope("step/clip"):
+            if grad_clip:
+                grads, gnorm = _clip_by_global_norm(grads, grad_clip)
+            else:
+                gnorm = _global_norm(grads)
 
         metrics = dict(metrics)
-        if telemetry:
-            from repro.telemetry.stats import collect
+        with jax.named_scope("opt/update"):
+            if telemetry:
+                from repro.telemetry.stats import collect
 
-            # the context manager lives entirely at trace time: the rules
-            # record tracer-valued SubspaceStats into the collector and the
-            # collected tree is returned as a regular jit output
-            with collect() as col:
+                # the context manager lives entirely at trace time: the
+                # rules record tracer-valued SubspaceStats into the
+                # collector and the collected tree is returned as a
+                # regular jit output
+                with collect() as col:
+                    updates, new_opt = optimizer.update(
+                        grads, state.opt_state, state.params)
+                tel = col.tree()
+                if tel:
+                    metrics["telemetry"] = tel
+            else:
                 updates, new_opt = optimizer.update(grads, state.opt_state,
                                                     state.params)
-            tel = col.tree()
-            if tel:
-                metrics["telemetry"] = tel
-        else:
-            updates, new_opt = optimizer.update(grads, state.opt_state,
-                                                state.params)
-        new_params = apply_updates(state.params, updates)
+        with jax.named_scope("step/apply"):
+            new_params = apply_updates(state.params, updates)
+            new_state = TrainState(state.step + 1, new_params, new_opt)
         metrics["grad_norm"] = gnorm
-        new_state = TrainState(state.step + 1, new_params, new_opt)
         if guard:
             from repro.train.resilience import all_finite_tree, select_tree
 
@@ -174,10 +187,11 @@ def make_train_step(cfg, optimizer, *, grad_clip: float = 1.0,
             # select between new and old state — donation-safe (the donated
             # old buffers feed the select, never aliased ambiguously), and
             # XLA folds select(p, x, x) for leaves the step didn't change.
-            flag = (jnp.isfinite(metrics["loss"])
-                    & jnp.isfinite(gnorm)
-                    & all_finite_tree(updates))
-            new_state = select_tree(flag, new_state, state)
+            with jax.named_scope("step/guard"):
+                flag = (jnp.isfinite(metrics["loss"])
+                        & jnp.isfinite(gnorm)
+                        & all_finite_tree(updates))
+                new_state = select_tree(flag, new_state, state)
             metrics["all_finite"] = flag
         return new_state, metrics
 

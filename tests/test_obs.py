@@ -2,8 +2,9 @@
 
 Covers the metrics registry (bucket edges, quantiles, labeled series,
 registration conflicts, enable/disable), the span tracer (nesting, ring
-wraparound, Chrome-trace validity, sink export), the exporters
-(Prometheus text format, JSONL), and the three instrumented layers:
+wraparound, Chrome-trace validity, sink export, profiler annotations),
+the Prometheus exporter, the device scopes of the train step, and the
+three instrumented layers:
 
   * serving — TTFT/ITL/queue-wait/E2E histograms must agree exactly with
     the per-request timestamps on the GenerationHandles (same clock, same
@@ -17,9 +18,15 @@ wraparound, Chrome-trace validity, sink export), the exporters
 The disabled-mode contract is pinned two ways: instruments record
 nothing while disabled, and the lowered HLO of a jitted train step is
 *bit-identical* with obs enabled vs disabled (the instrumentation is
-host-side only and can never alter a traced graph).
+host-side only and can never alter a traced graph). The device scopes
+are compile-time metadata: every op of the compiled step carries one,
+and the compiled step differs from an unscoped one in metadata alone.
 """
+import contextlib
+import dataclasses
+import glob
 import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +34,7 @@ import numpy as np
 import pytest
 
 from repro import obs
+from repro.obs import tracing
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.tracing import SpanTracer
 
@@ -242,23 +250,6 @@ def test_prometheus_exporter_atomic_write(tmp_path):
     assert not path.with_suffix(".prom.tmp").exists()
 
 
-def test_jsonl_exporter_appends_snapshots(tmp_path):
-    from repro.obs.exporters import JSONLExporter
-
-    r = MetricsRegistry()
-    h = r.histogram("h_seconds", edges=(1.0, 2.0))
-    h.observe(0.5, ())
-    exp = JSONLExporter(r, str(tmp_path / "m.jsonl"))
-    exp.write(step=10)
-    h.observe(1.5)
-    exp.write(step=20)
-    lines = [json.loads(ln) for ln in
-             (tmp_path / "m.jsonl").read_text().splitlines()]
-    assert [ln["step"] for ln in lines] == [10, 20]
-    series = lines[1]["metrics"]["h_seconds"]["series"][""]
-    assert series["count"] == 2 and "p50" in series and "p99" in series
-
-
 # ---------------------------------------------------------------------------
 # telemetry sink: ring wraparound + bucket flush ordering (satellite)
 # ---------------------------------------------------------------------------
@@ -328,6 +319,148 @@ def test_obs_toggle_keeps_train_step_hlo_bit_identical():
     obs.enable()
     hlo_on = jax.jit(step).lower(state, batch).as_text()
     assert hlo_off == hlo_on
+
+
+# ---------------------------------------------------------------------------
+# device scopes: every op of the compiled train step names its layer
+# ---------------------------------------------------------------------------
+SCOPES = ("model/embed", "model/blocks", "model/attn", "model/mlp",
+          "model/head", "step/clip", "step/apply", "opt/update", "opt/ef",
+          "opt/select_project", "opt/adam", "opt/backproject",
+          "opt/full_adam")
+# instructions that run no device op of their own
+_STRUCTURE = ("parameter", "constant", "get-tuple-element", "tuple",
+              "bitcast", "while", "conditional", "call")
+
+
+def _compiled_step_hlo(fused: str) -> str:
+    """A tiny rematerialised dct_adamw step, compiled (the Pallas kernels
+    in interpret mode for ``fused="on"``)."""
+    from repro.models import transformer as T
+    from repro.optim.api import get_optimizer
+    from repro.train.steps import TrainState, make_train_step
+
+    cfg = dataclasses.replace(_tiny_cfg(), remat=True)
+    opt = get_optimizer("dct_adamw", lr=1e-3, rank=8, fused=fused)
+    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    state = TrainState(jnp.zeros((), jnp.int32), params, opt.init(params))
+    batch = {"tokens": jnp.zeros((2, 16), jnp.int32),
+             "targets": jnp.zeros((2, 16), jnp.int32)}
+    step = jax.jit(make_train_step(cfg, opt))
+    return step.lower(state, batch).compile().as_text()
+
+
+@pytest.mark.parametrize("fused", ["on", "off"])
+def test_every_compiled_step_op_carries_a_scope(fused):
+    from bench import scopes
+
+    ops = {k: v for k, v in scopes.hlo_ops(_compiled_step_hlo(fused)).items()
+           if v.opcode not in _STRUCTURE}
+    # every op the program traced (a name-stack op_name) is in a scope
+    traced = {k: v for k, v in ops.items() if "/" in v.op_name}
+    bare = sorted(f"{k}: {v.op_name}" for k, v in traced.items()
+                  if scopes.scope_of(v.op_name) is None)
+    assert len(bare) < 0.05 * len(traced), bare
+    # and the compiler's own ops (copies) take the scope they serve
+    rest = sorted(f"{k}: {v.opcode}" for k, v in ops.items()
+                  if v.scope is None)
+    assert len(rest) < 0.05 * len(ops), rest
+    assert set(SCOPES) <= {v.scope for v in ops.values()}
+
+
+def _numbered_in_order(hlo_text: str) -> str:
+    """Instruction names replaced by their order of first use: a second
+    lowering in one process numbers some instructions differently."""
+    names: dict = {}
+    return re.sub(r"%[\w.\-]+",
+                  lambda m: names.setdefault(m.group(0), f"%i{len(names)}"),
+                  hlo_text)
+
+
+def test_scopes_change_only_the_metadata(monkeypatch):
+    """The compiled step with its scopes equals, instruction for
+    instruction, the step compiled with every scope a no-op."""
+    from bench import scopes
+
+    scoped = _compiled_step_hlo("off")
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = _compiled_step_hlo("off")
+    assert "model/attn" in scoped and "model/attn" not in bare
+    assert _numbered_in_order(scopes.strip_metadata(scoped)) == \
+        _numbered_in_order(scopes.strip_metadata(bare))
+
+
+def _host_events(trace_dir) -> list:
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    data = ProfileData.from_file(path)
+    return [e for plane in data.planes if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events]
+
+
+def test_enabled_span_is_a_profiler_annotation(tmp_path):
+    on = SpanTracer(capacity=4)
+    off = SpanTracer(capacity=4, enabled=False)
+    jax.profiler.start_trace(str(tmp_path))
+    with on.span("obs-test/enabled"):
+        pass
+    with off.span("obs-test/disabled"):
+        pass
+    jax.profiler.stop_trace()
+    names = [e.name for e in _host_events(tmp_path)]
+    assert names.count("obs-test/enabled") == 1
+    assert "obs-test/disabled" not in names
+    assert off.span("x") is tracing._NOOP
+    (rec,) = on.records()
+    assert rec["name"] == "obs-test/enabled"
+
+
+def test_trainer_spans_and_steps_reach_the_profiler(tmp_path):
+    from repro.data.synthetic import SyntheticLM
+    from repro.models import transformer as T
+    from repro.optim.api import get_optimizer
+    from repro.train.loop import Trainer
+    from repro.train.steps import TrainState, make_train_step
+
+    cfg = _tiny_cfg()
+    opt = get_optimizer("adamw", lr=1e-3)
+    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    ds = SyntheticLM(vocab_size=cfg.vocab_size, seq_len=16,
+                     global_batch=2, seed=0)
+
+    def trainer():
+        return Trainer(
+            train_step=jax.jit(make_train_step(cfg, opt)),
+            init_state_fn=lambda: TrainState(jnp.zeros((), jnp.int32),
+                                             params, opt.init(params)),
+            batch_fn=lambda i: ds.batch(jnp.int32(i)),
+            log_fn=lambda s: None)
+
+    trainer().run(1, resume=False)           # compiles outside the trace
+    obs.enable()
+    jax.profiler.start_trace(str(tmp_path / "on"))
+    trainer().run(3, resume=False)
+    jax.profiler.stop_trace()
+    obs.disable()
+    events = _host_events(tmp_path / "on")
+    names = [e.name for e in events]
+    for span in ("train/data_wait", "train/dispatch", "train/host_sync"):
+        assert names.count(span) == 3, span
+    steps = [e for e in events if e.name == "train"]
+    assert len(steps) == 3
+    # each phase lies inside its step's marker, on one clock
+    for e in events:
+        if e.name == "train/dispatch":
+            assert any(s.start_ns <= e.start_ns and e.end_ns <= s.end_ns
+                       for s in steps)
+    # with obs off the loop leaves nothing in a trace
+    jax.profiler.start_trace(str(tmp_path / "off"))
+    trainer().run(2, resume=False)
+    jax.profiler.stop_trace()
+    names = {e.name for e in _host_events(tmp_path / "off")}
+    assert not names & {"train", "train/data_wait", "train/dispatch"}
 
 
 # ---------------------------------------------------------------------------

@@ -107,3 +107,42 @@ def test_flash_decode_compiles(one_chip, num_splits):
         _sds(one_chip, (b, hq, hd), jnp.bfloat16), pool, pool,
         _sds(one_chip, (b, maxb), jnp.int32), _sds(one_chip, (b,), jnp.int32))
     assert "tpu_custom_call" in hlo
+
+
+def test_scoped_train_step_keeps_its_kernels(one_chip, monkeypatch):
+    """A whole dct_adamw step with its name scopes, compiled for the
+    chip: the benchmark's reader still finds each Pallas kernel by its
+    wrapper (``jit(<wrapper>)/pallas_call`` ends the ``op_name``), and
+    each kernel lies in the optimizer scope of its phase."""
+    from bench import scopes, xtrace
+    from repro.kernels import ops
+    from repro.models import transformer as T
+    from repro.models.config import ModelConfig
+    from repro.optim.api import get_optimizer
+    from repro.train.steps import TrainState, make_train_step
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    cfg = ModelConfig(
+        name="scoped-tiny", family="dense", d_model=256, n_heads=2,
+        n_kv_heads=2, d_ff=512, vocab_size=512,
+        schedule=((("attn",), 2),), param_dtype="float32",
+        compute_dtype="bfloat16", q_chunk=128, kv_chunk=128)
+    opt = get_optimizer("dct_adamw", lr=1e-3, rank=128, fused="on")
+    shapes = jax.eval_shape(
+        lambda: (lambda p: TrainState(jnp.zeros((), jnp.int32), p,
+                                      opt.init(p)))(
+            T.init_params(cfg, jax.random.PRNGKey(0))))
+    state = jax.tree.map(lambda x: _sds(one_chip, x.shape, x.dtype), shapes)
+    tokens = _sds(one_chip, (1, 256), jnp.int32)
+    hlo = _compile_hlo(make_train_step(cfg, opt), state,
+                       {"tokens": tokens, "targets": tokens})
+    kernels = xtrace.hlo_kernels(hlo)
+    found = {info["kernel"] for info in kernels.values()}
+    assert found == {"dct_project", "colgather_matmul_dual", "quantize_ef",
+                     "dequant_add_ef"}
+    phase = {"dct_project": "opt/select_project",
+             "colgather_matmul_dual": "opt/backproject",
+             "quantize_ef": "opt/ef", "dequant_add_ef": "opt/ef"}
+    ops_ = scopes.hlo_ops(hlo)
+    for instr, info in kernels.items():
+        assert ops_[instr].scope == phase[info["kernel"]], instr
