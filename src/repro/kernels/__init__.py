@@ -6,9 +6,11 @@
                      variant back-projects two factors from one gather
   newton_schulz    — NS5 on the low-rank factor (r-sized Gram in VMEM)
   quant_ef         — int8 error-feedback quantize / fused dequant-add
-  flash_attention  — online-softmax attention, GQA/causal/window, VMEM-
-                     resident softmax state (the train/prefill memory-term
-                     fix identified in EXPERIMENTS.md §Roofline)
+  flash_attention  — train/prefill attention, GQA/causal/window, with a
+                     fused FlashAttention-2 backward (custom VJP): score
+                     tiles stay in VMEM, residuals are q, k, v, o and a
+                     per-row logsumexp; models/layers.py:attention takes
+                     it on a TPU
   flash_decode     — paged single-query decode attention: K/V gathered
                      through the serve block table via scalar-prefetched
                      index maps, split-KV parallel over cache blocks with

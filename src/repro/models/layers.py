@@ -1,4 +1,5 @@
-"""Model primitives: norms, RoPE, blockwise (flash-style) attention, MLPs.
+"""Model primitives: norms, RoPE, attention (Pallas flash kernel or
+blockwise jnp), MLPs.
 
 Pure-functional: params are nested dicts of arrays; every apply function is
 shape-polymorphic over leading batch dims where possible. Activations are
@@ -14,7 +15,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.parallel.sharding import shard
+from repro import obs
+from repro.parallel.sharding import active_mesh, shard
 
 
 # ---------------------------------------------------------------------------
@@ -168,6 +170,51 @@ def blockwise_attention(q, k, v, *, causal: bool, window: int | None = None,
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
+_ATTN_CALLS = obs.registry().counter(
+    "model_attn_calls_total",
+    "traced train/prefill attention calls, by the path they took",
+    labels=("path",))
+
+
+def _flash_applies(q, k, v, q_offset) -> bool:
+    """The Pallas flash kernel computes this call: on a TPU, self-attention
+    from position 0 with v's head size equal to q's, a sequence the
+    kernel's blocks tile, and no mesh that would partition the kernel
+    (Mosaic refuses a Pallas call in a GSPMD-partitioned program; inside
+    a ``shard_map`` every axis is manual and the call is local)."""
+    from repro.kernels import ops
+    from repro.kernels.flash_attention import supports
+
+    if not ops.on_tpu():
+        return False
+    b, sq, hq, hd = q.shape
+    hkv = k.shape[2]
+    if (k.shape[1] != sq or not isinstance(q_offset, int) or q_offset
+            or v.shape[-1] != hd or hq % hkv
+            or not q.dtype == k.dtype == v.dtype
+            or not supports(sq)):
+        return False
+    mesh = active_mesh()
+    return mesh is None or mesh.are_all_axes_manual
+
+
+def attention(q, k, v, *, causal: bool, window: int | None = None,
+              q_chunk: int = 512, kv_chunk: int = 512, q_offset: int = 0):
+    """Train/prefill attention: the Pallas flash kernel with its fused
+    backward (kernels/flash_attention.py) where :func:`_flash_applies`,
+    else :func:`blockwise_attention` with the same arguments. Each traced
+    call counts in ``model_attn_calls_total{path}``."""
+    if _flash_applies(q, k, v, q_offset):
+        from repro.kernels.ops import flash_attention_op
+
+        _ATTN_CALLS.inc(labels=("flash",))
+        return flash_attention_op(q, k, v, causal=causal, window=window)
+    _ATTN_CALLS.inc(labels=("blockwise",))
+    return blockwise_attention(q, k, v, causal=causal, window=window,
+                               q_chunk=q_chunk, kv_chunk=kv_chunk,
+                               q_offset=q_offset)
+
+
 def sp_blockwise_attention(q, k, v, *, causal: bool, window=None,
                            q_chunk: int = 512, kv_chunk: int = 512):
     """Sequence-parallel attention (§Perf iter-1, beyond-paper).
@@ -178,33 +225,35 @@ def sp_blockwise_attention(q, k, v, *, causal: bool, window=None,
     Without this, GSPMD re-gathers operands inside every (q-chunk,
     kv-chunk) loop iteration — the dominant collective in the train
     baseline. Head counts never need to divide tp (qwen's 40/8 heads).
-    Falls back to the plain path when no mesh / not divisible.
+    Falls back to :func:`attention` over the whole sequence when no mesh /
+    not divisible; the per-shard call (a traced ``q_offset``) is
+    blockwise.
     """
     from jax.sharding import PartitionSpec as P
-    from repro.parallel.sharding import active_mesh, dp_axes, tp_axis
+    from repro.parallel.sharding import dp_axes, tp_axis
 
     mesh = active_mesh()
     tp = tp_axis(mesh)
     b, s, hq, hd = q.shape
     if mesh is None or tp is None:
-        return blockwise_attention(q, k, v, causal=causal, window=window,
-                                   q_chunk=q_chunk, kv_chunk=kv_chunk)
+        return attention(q, k, v, causal=causal, window=window,
+                         q_chunk=q_chunk, kv_chunk=kv_chunk)
     tp_n = mesh.shape[tp]
     dp = dp_axes(mesh)
     dp_n = 1
     for a in dp:
         dp_n *= mesh.shape[a]
     if s % tp_n or (s // tp_n) < 64 or (dp and b % dp_n):
-        return blockwise_attention(q, k, v, causal=causal, window=window,
-                                   q_chunk=q_chunk, kv_chunk=kv_chunk)
+        return attention(q, k, v, causal=causal, window=window,
+                         q_chunk=q_chunk, kv_chunk=kv_chunk)
     s_loc = s // tp_n
     dps = dp if dp else None
 
     def local(qs, ks, vs):
         off = jax.lax.axis_index(tp) * s_loc
-        return blockwise_attention(qs, ks, vs, causal=causal, window=window,
-                                   q_chunk=min(q_chunk, s_loc),
-                                   kv_chunk=kv_chunk, q_offset=off)
+        return attention(qs, ks, vs, causal=causal, window=window,
+                         q_chunk=min(q_chunk, s_loc), kv_chunk=kv_chunk,
+                         q_offset=off)
 
     return jax.shard_map(
         local, mesh=mesh,

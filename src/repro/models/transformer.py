@@ -30,7 +30,7 @@ from repro.parallel.sharding import shard
 
 from .layers import (
     apply_rope,
-    blockwise_attention,
+    attention,
     chunk_attention,
     decode_attention,
     dense_init,
@@ -284,9 +284,9 @@ def _gqa_apply(p, x, cfg, *, causal, window=None, kv_src=None, rope=True,
         out = shard(out, "batch", "sp", None, None)
     else:
         q = shard(q, "batch", None, "tp", None)
-        out = blockwise_attention(q, k, v, causal=causal, window=window,
-                                  q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
-                                  q_offset=q_offset)
+        out = attention(q, k, v, causal=causal, window=window,
+                        q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
+                        q_offset=q_offset)
     out = out.reshape(b, s, hq * hd)
     y = out @ p["wo"]["kernel"]
     if "bias" in p["wo"]:
@@ -324,9 +324,8 @@ def _mla_apply(p, x, cfg, *, return_kv=False):
                                      q_chunk=cfg.q_chunk,
                                      kv_chunk=cfg.kv_chunk)
     else:
-        out = blockwise_attention(q, k, v, causal=True,
-                                  q_chunk=cfg.q_chunk,
-                                  kv_chunk=cfg.kv_chunk)
+        out = attention(q, k, v, causal=True, q_chunk=cfg.q_chunk,
+                        kv_chunk=cfg.kv_chunk)
     y = out.reshape(b, s, h * vd) @ p["wo"]["kernel"]
     if return_kv:
         # decode cache stores the *latent* (c_kv) + roped shared k_rope

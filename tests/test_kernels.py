@@ -263,3 +263,68 @@ def test_flash_attention_matches_blockwise_model_path():
                         interpret=True)
     b = blockwise_attention(q, k, v, causal=True, q_chunk=64, kv_chunk=64)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-5)
+
+
+@pytest.mark.parametrize("b,s,hq,hkv,hd,causal,window,dtype,bq,bk", [
+    (1, 256, 5, 1, 128, True, None, jnp.float32, 128, 128),    # GQA 5/1
+    (1, 256, 4, 2, 96, True, None, jnp.bfloat16, 128, 128),    # GQA 4/2
+    (2, 256, 2, 2, 128, False, None, jnp.float32, 128, 128),   # MHA
+    (1, 256, 4, 4, 96, True, None, jnp.bfloat16, 128, 128),    # MHA
+    (1, 512, 4, 2, 128, True, 200, jnp.float32, 128, 128),     # window
+    (1, 512, 2, 1, 96, False, 150, jnp.float32, 128, 128),
+    (1, 512, 4, 4, 96, True, None, jnp.bfloat16, 256, 128),    # q block
+    (1, 512, 4, 2, 128, True, 192, jnp.float32, 128, 256),     # straddles
+])
+def test_flash_attention_grads_match_ref(b, s, hq, hkv, hd, causal, window,
+                                         dtype, bq, bk):
+    """dq, dk, dv through the kernel's custom VJP against autodiff of the
+    dense oracle and of the model's blockwise path; the forward's
+    logsumexp against the oracle's scores."""
+    from repro.kernels.flash_attention import (_Cfg, flash_attention,
+                                               flash_attention_fwd)
+    from repro.kernels.ref import flash_attention_ref
+    from repro.models.layers import blockwise_attention
+
+    rng = np.random.default_rng(s + 10 * hq + hkv + hd)
+    q = jnp.asarray(rng.standard_normal((b, s, hq, hd)), dtype)
+    k = jnp.asarray(rng.standard_normal((b, s, hkv, hd)), dtype)
+    v = jnp.asarray(rng.standard_normal((b, s, hkv, hd)), dtype)
+    w = jnp.asarray(rng.standard_normal((b, s, hq, hd)), jnp.float32)
+
+    def grads(attn):
+        loss = lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) * w)
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    got = grads(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, window=window, block_q=bq, block_k=bk,
+        interpret=True))
+    want = grads(lambda q, k, v: flash_attention_ref(
+        q, k, v, causal=causal, window=window))
+    model = grads(lambda q, k, v: blockwise_attention(
+        q, k, v, causal=causal, window=window, q_chunk=bq, kv_chunk=bk))
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    for name, g, r, m in zip("qkv", got, want, model):
+        assert g.dtype == r.dtype == dtype, name
+        g, r, m = (np.asarray(x, np.float32) for x in (g, r, m))
+        scale = np.abs(r).max()
+        np.testing.assert_allclose(g, r, atol=tol * scale, err_msg=name)
+        np.testing.assert_allclose(g, m, atol=tol * scale, err_msg=name)
+
+    heads = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, s, hd)
+    c = _Cfg(causal, window, bq, bk, True)
+    _, lse = flash_attention_fwd(heads(q), heads(k), heads(v),
+                                 group=hq // hkv, c=c)
+    kx = jnp.repeat(k, hq // hkv, axis=2).astype(jnp.float32)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), kx,
+                        precision="highest") / np.sqrt(hd)
+    pos = np.arange(s)
+    keep = np.ones((s, s), bool)
+    if causal:
+        keep &= pos[:, None] >= pos[None, :]
+    if window is not None:
+        keep &= pos[:, None] - pos[None, :] < window
+    lse_ref = jax.nn.logsumexp(jnp.where(keep, scores, -jnp.inf), axis=-1)
+    assert lse.shape == (b * hq, 1, s) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(lse[:, 0]),
+                               np.asarray(lse_ref.reshape(b * hq, s)),
+                               atol=1e-4, rtol=1e-5)
