@@ -45,7 +45,6 @@ def main(argv=None) -> int:
     spec, job = found["spec"], found["job"]
     harness.check_device(found["cell"]["chips"])
     harness.enable_compile_cache()
-    Reference = harness.reference_class(spec)
     n = job["check_steps"]
     out = open(args.out, "a")
 
@@ -59,7 +58,7 @@ def main(argv=None) -> int:
                       + seeds(args.fault_seeds)))
     for seed in todo:
         t = time.time()
-        ref = Reference(spec["model"], job).run(seed, n)
+        ref = harness.reference(spec, job).run(seed, n)
         ref_s = time.time() - t
         if seed in seeds(args.seeds):
             prog = program.Program(spec, job, seed)
@@ -82,7 +81,7 @@ def main(argv=None) -> int:
                      ("fault_half_batch", dict(fault="half_batch"))]
         for side, kw in runs:
             free()
-            other = Reference(spec["model"], job, **kw).run(seed, n)
+            other = harness.reference(spec, job, **kw).run(seed, n)
             emit({"workload": args.workload, "seed": seed, "side": side,
                   "numbers": check.numbers(other, ref),
                   "losses": other["losses"],

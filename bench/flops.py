@@ -1,26 +1,10 @@
-"""Operations and bytes that the work needs, from shapes alone, so that
-they are the same whatever implements it. Recomputation is not counted.
+"""Operations and bytes of the optimizer's kernels, from shapes alone, so
+that they are the same whatever implements them. A model's FLOPs per
+token are its reference module's ``model_flops_per_token``.
 """
 from __future__ import annotations
 
 F32 = 4
-
-
-def matmul_params(model: dict) -> int:
-    """Parameters that take part in a matmul per token: every layer's
-    projections and MLP, and the LM head. The embedding is a gather."""
-    d, f, v = model["d_model"], model["d_ff"], model["vocab_size"]
-    hq, hkv, hd = model["n_heads"], model["n_kv_heads"], model["head_dim"]
-    layer = d * hq * hd + 2 * d * hkv * hd + hq * hd * d + 3 * d * f
-    return model["n_layers"] * layer + v * d
-
-
-def model_flops_per_token(model: dict, seq_len: int) -> float:
-    """Forward and backward: ``6 N`` for the matmul parameters, plus causal
-    attention, ``6 S hq hd`` per layer (``QK^T`` and ``PV`` over the
-    lower triangle, backward twice the forward)."""
-    attn = 6 * seq_len * model["n_heads"] * model["head_dim"]
-    return 6.0 * matmul_params(model) + model["n_layers"] * attn
 
 
 def dct_project(batch: int, m: int, n: int) -> tuple[float, float]:

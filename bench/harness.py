@@ -11,6 +11,26 @@ metric is found by name under the benchmark's root:
 - ``bench/reference/<name>.py``: the plain reference a configuration
   file names.
 
+A configuration's own files define its model; the harness knows no
+architecture. A configuration is added by these files and entries alone:
+
+- ``bench/configs/<name>.json``: ``registry`` (the program's config it
+  cuts), ``reference`` (the module's name), ``model`` (the keys put into
+  the registry's config, its layers as ``schedule``, a list of ``[pattern,
+  repeats]`` whose segments are the registry's in order, each repeated 1
+  to the registry's times; ``n_layers`` alone means ``[[["attn"],
+  n_layers]]``), a top-level ``init`` (``{leaf-path suffix: "fan_in" |
+  std}`` for the leaves that ``weights.py``'s common rules do not know),
+  ``published``, ``reduced``, ``assumed``, ``deployment``, ``precision``;
+- ``bench/reference/<name>.py``: ``Reference(model, job, init=...)``, whose
+  ``run(seed, steps)`` returns ``losses``, ``grad_norms``,
+  ``true_grad_norms`` and ``delta_norms``; ``param_specs(model)``, laid
+  out as the program's parameters; ``model_flops_per_token(model,
+  seq_len)``, which ``mfu`` reads as ``ctx.flops_per_token``;
+- ``bench/traffic/<job>.json`` and ``bench/limits/<cell>.json``;
+- any new ``bench/metrics/<name>.py``;
+- the entries in ``BENCHMARK.json``.
+
 The last line on standard output is the result's JSON object.
 """
 from __future__ import annotations
@@ -81,9 +101,24 @@ def metric_reader(root: str, name: str):
     return mod.read
 
 
-def reference_class(spec: dict):
-    mod = importlib.import_module("bench.reference." + spec["reference"])
-    return mod.Reference
+def model_of(spec: dict) -> dict:
+    """The file's ``model``, with ``n_layers`` summed from its ``schedule``
+    where the file states none: what the reference and the readers see."""
+    model = dict(spec["model"])
+    if "n_layers" not in model:
+        model["n_layers"] = sum(len(p) * r for p, r in model["schedule"])
+    return model
+
+
+def reference_module(spec: dict):
+    return importlib.import_module("bench.reference." + spec["reference"])
+
+
+def reference(spec: dict, job: dict, **kw):
+    """The file's plain reference, drawing its weights by the file's
+    ``init`` rules as the program does."""
+    return reference_module(spec).Reference(
+        model_of(spec), job, init=spec.get("init", {}), **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -235,10 +270,13 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
         kernels = xtrace.hlo_kernels(hlo)
         ids = sorted(tr.devices)[:cell["chips"]]
         busy = [xtrace.busy_seconds(tr, d) for d in ids]
+        model = model_of(spec)
         ctx = types.SimpleNamespace(
             trace=tr, kernels=kernels, devices=ids, steps=n_steps,
             window_s=xtrace.window_seconds(tr), chips=cell["chips"],
-            peak=peak, model=spec["model"], job=job,
+            peak=peak, model=model, job=job,
+            flops_per_token=reference_module(spec).model_flops_per_token(
+                model, job["seq_len"]),
             spans=[r for r in obs.tracer().records() if r],
             facts={"opt_state_bytes": opt_bytes,
                    "tokens_per_step": tokens_per_step})
@@ -259,8 +297,7 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     jax.clear_caches()
 
     t_ref = time.time()
-    ref = reference_class(spec)(spec["model"], job).run(seed,
-                                                        job["check_steps"])
+    ref = reference(spec, job).run(seed, job["check_steps"])
     ref_s = time.time() - t_ref
     nums = check.numbers(prog_nums, ref)
     ok, detail = check.verdict(nums, found["limits"])

@@ -27,27 +27,50 @@ import jax.numpy as jnp
 from . import synthetic, weights
 
 
+def schedule(model: dict, registry) -> tuple:
+    """The layer schedule a configuration file's ``model`` states: its
+    ``schedule``, a list of ``[pattern, repeats]``, or ``n_layers`` alone,
+    meaning ``[[["attn"], n_layers]]``. Its segments are the registry's, in
+    the same order and with the same patterns, each repeated from once to
+    as often as in the registry: whole periods, with a leading segment
+    kept. A file cannot state a layout that the source does not have."""
+    if "schedule" in model:
+        stated = tuple((tuple(p), r) for p, r in model["schedule"])
+    else:
+        stated = ((("attn",), model["n_layers"]),)
+    if [p for p, _ in stated] != [p for p, _ in registry]:
+        raise ValueError(f"the schedule's patterns {[p for p, _ in stated]} "
+                         f"are not the registry's {[p for p, _ in registry]}")
+    for (pattern, r), (_, most) in zip(stated, registry):
+        if r not in range(1, most + 1):
+            raise ValueError(f"{pattern} repeats {r!r} times; the registry "
+                             f"allows 1 to {most}")
+    return stated
+
+
 def model_config(spec: dict, job: dict | None = None):
     """The program's ``ModelConfig`` for a configuration file: the
-    registry's published config with the file's ``model`` keys put in,
-    and the job's rows per micro-batch (``train_microbatch``). Fails when
-    the result disagrees with the file on any key."""
+    registry's published config with the file's ``model`` keys put in, its
+    layers as :func:`schedule` reads them, and the job's rows per
+    micro-batch (``train_microbatch``). Fails when the result disagrees
+    with the file on any key."""
     from repro.configs.registry import get_config
 
     base = get_config(spec["registry"])
     model = dict(spec["model"])
     if job is not None:
         model["train_microbatch"] = job["rows_per_micro"]
-    n_layers = model.pop("n_layers")
-    kinds = base.block_kinds()
-    if kinds != ("attn",):
-        raise ValueError(f"{spec['name']}: only plain attn stacks are "
-                         f"supported here, the registry has {kinds}")
-    cfg = dataclasses.replace(base, schedule=((("attn",), n_layers),),
-                              **model)
-    stated = dict(spec["model"])
-    for key, want in stated.items():
-        got = cfg.n_layers if key == "n_layers" else getattr(cfg, key)
+    layers = schedule(model, base.schedule)
+    model.pop("n_layers", None)
+    model.pop("schedule", None)
+    cfg = dataclasses.replace(base, schedule=layers, **model)
+    for key, want in spec["model"].items():
+        if key == "n_layers":
+            got = cfg.n_layers
+        elif key == "schedule":
+            got = [[list(p), r] for p, r in cfg.schedule]
+        else:
+            got = getattr(cfg, key)
         if got != want:
             raise ValueError(f"{spec['name']}: {key} is {got!r}, the "
                              f"configuration file states {want!r}")
@@ -132,6 +155,7 @@ class Program:
         from repro.train.steps import make_train_step
 
         self.spec, self.job, self.seed = spec, job, seed
+        self.init = spec.get("init", {})
         self.cfg = model_config(spec, job)
         lr = cosine_warmup(job["lr"], job["warmup_steps"],
                            job["schedule_steps"])
@@ -153,7 +177,8 @@ class Program:
         dtype, and the optimizer's initial state: two jitted calls."""
         from repro.train.steps import TrainState
 
-        flat = weights.draw_all(synthetic.seed_key(self.seed, 0), self.specs)
+        flat = weights.draw_all(synthetic.seed_key(self.seed, 0), self.specs,
+                                self.init)
         params = _unflatten_like(self.cfg, flat)
         opt_state = jax.jit(self.opt.init)(params)
         return TrainState(jnp.zeros((), jnp.int32), params, opt_state)
@@ -162,7 +187,8 @@ class Program:
         """Per-leaf ``||theta - theta_0||``, drawing each initial leaf
         again alone so that no second copy of the weights is held."""
         key = synthetic.seed_key(self.seed, 0)
-        return {_path(kp): _delta_norm(key, x, _path(kp))
+        return {_path(kp): _delta(x, weights.draw_one(
+                    key, _path(kp), x.shape, x.dtype, self.init))
                 for kp, x in jax.tree_util.tree_leaves_with_path(params)}
 
     def start(self) -> Run:
@@ -247,10 +273,6 @@ def _unflatten_like(cfg, flat: dict):
 @jax.jit
 def _delta(x, x0):
     return jnp.linalg.norm(x.astype(jnp.float32) - x0.astype(jnp.float32))
-
-
-def _delta_norm(key, x, path):
-    return _delta(x, weights.draw_one(key, path, x.shape, x.dtype))
 
 
 def window_steps(step_seconds: list, seconds: float) -> int:

@@ -82,6 +82,23 @@ def param_specs(model: dict) -> dict:
     return {p: (tuple(s), str(jnp.dtype(t))) for p, (s, t) in specs.items()}
 
 
+def matmul_params(model: dict) -> int:
+    """Parameters that take part in a matmul per token: every layer's
+    projections and MLP, and the LM head. The embedding is a gather."""
+    d, f, v = model["d_model"], model["d_ff"], model["vocab_size"]
+    hq, hkv, hd = model["n_heads"], model["n_kv_heads"], model["head_dim"]
+    layer = d * hq * hd + 2 * d * hkv * hd + hq * hd * d + 3 * d * f
+    return model["n_layers"] * layer + v * d
+
+
+def model_flops_per_token(model: dict, seq_len: int) -> float:
+    """Forward and backward: ``6 N`` for the matmul parameters, plus causal
+    attention, ``6 S hq hd`` per layer (``QK^T`` and ``PV`` over the
+    lower triangle, backward twice the forward)."""
+    attn = 6 * seq_len * model["n_heads"] * model["head_dim"]
+    return 6.0 * matmul_params(model) + model["n_layers"] * attn
+
+
 def is_lowrank(path: str, shape) -> bool:
     if any(h in path.lower() for h in FULL_HINTS):
         return False
@@ -364,10 +381,11 @@ class Reference:
     and reports what the check compares."""
 
     def __init__(self, model: dict, job: dict, mode: str = "fp32",
-                 fault: str | None = None):
+                 fault: str | None = None, init: dict | None = None):
         if fault not in FAULTS:
             raise ValueError(f"unknown fault {fault!r}; have {FAULTS}")
         self.model, self.job, self.mode, self.fault = model, job, mode, fault
+        self.init = init
         self.args = {"rank": 128, "weight_decay": 0.0, "b1": 0.9,
                      "b2": 0.999, "eps": 1e-8, **job["optimizer_args"]}
         self.specs = param_specs(model)
@@ -398,7 +416,7 @@ class Reference:
         model, job, mode = self.model, self.job, self.mode
         a = self.args
         key = synthetic.seed_key(seed, 0)
-        params = split_layers(weights.draw_all(key, self.specs))
+        params = split_layers(weights.draw_all(key, self.specs, self.init))
         state = self._init_state(params)
         batch = synthetic.make_batch_fn(model["vocab_size"], job["seq_len"],
                                         job["rows_per_step"], seed,
@@ -453,7 +471,7 @@ class Reference:
             if t == 1:
                 out["grad_norms"] = self._grad_norms(state)
         for path, (shape, dtype) in self.specs.items():
-            x0 = weights.draw_one(key, path, shape, dtype)
+            x0 = weights.draw_one(key, path, shape, dtype, self.init)
             x = params[path]
             if isinstance(x, list):
                 x = jnp.stack(x)

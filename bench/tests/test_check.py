@@ -60,6 +60,13 @@ def test_traced_run_is_correct(root):
     r = run(root, trace=True)
     assert r["correct"] is True, r["check"]
     assert "mfu" in r["metrics"] and "opt_state_gib" in r["metrics"]
+    # mfu counts the FLOPs of the reference module the file names
+    peak = next(iter(harness.load_json(harness.PEAKS)["devices"].values()))
+    work = (dense_decoder.model_flops_per_token(tiny.MODEL,
+                                                tiny.JOB["seq_len"])
+            * tiny.JOB["rows_per_step"] * tiny.JOB["seq_len"] * r["attempted"])
+    assert r["metrics"]["mfu"]["value"] == pytest.approx(
+        100 * work / (r["device"]["window_s"] * peak["bf16_flops"]))
     assert r["device"]["window_s"] > 0
     assert set(r["breakdown"]) == {"device_ops", "idle_gaps"}
 
